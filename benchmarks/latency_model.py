@@ -12,7 +12,7 @@ from __future__ import annotations
 
 from benchmarks.common import emit, save_json
 from repro.configs import get_arch
-from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+from repro.launch.roofline import DRYRUN_DEVICE_KIND, device_peaks
 
 
 def decode_step_model(arch, batch, seq_len, cr=1.0):
@@ -34,7 +34,8 @@ def decode_step_model(arch, batch, seq_len, cr=1.0):
     params_bytes = arch.param_count(active_only=True) * 2
     kv_bytes = 2 * n * batch * l_eff * d_kv * 2
     reads = params_bytes + kv_bytes
-    lat = max(flops / PEAK_FLOPS, reads / HBM_BW)
+    peaks = device_peaks(DRYRUN_DEVICE_KIND)
+    lat = max(flops / peaks["flops"], reads / peaks["hbm_bw"])
     return {
         "latency_s": lat,
         "kv_share": kv_bytes / reads,

@@ -64,6 +64,8 @@ def main(argv=None) -> int:
                     help="relative tolerance for --check comparisons")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     from benchmarks import common
     from benchmarks import (ablation_eviction, continuous_batching, cr_profile,
                             cr_sweep, data_efficiency, decode_path,

@@ -30,6 +30,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# Scalar memory of one TPU v5e core; the scalar-prefetched block table lives
+# there.  The reserve is a margin for the scalars the compiler places there
+# itself.
+SMEM_BYTES = 1 << 20
+SMEM_RESERVE = 4 << 10
 
 
 class DecodeConfig(NamedTuple):
@@ -72,7 +77,7 @@ def _decode_kernel(tbl_ref, n_ref, q_ref, k_ref, v_ref, valid_ref,
         s = s * (cfg.orig_dh ** -0.5)
         if cfg.logit_cap is not None:
             s = cfg.logit_cap * jnp.tanh(s / cfg.logit_cap)
-        live = valid_ref[0][None, :] != 0                 # (1, BP)
+        live = valid_ref[0, 0] != 0                       # (1, BP)
         s = jnp.where(live, s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -88,7 +93,7 @@ def _decode_kernel(tbl_ref, n_ref, q_ref, k_ref, v_ref, valid_ref,
             # unnormalized weights relative to the running max at THIS block;
             # the wrapper rescales by exp(m_blk - m_final) / l_final
             w_ref[0, 0] = p                               # (G, BP)
-            mb_ref[0, 0] = m_new[:, 0]                    # (G,)
+            mb_ref[0, 0] = m_new                          # (G, 1)
 
     @pl.when(i == ni - 1)
     def _finish():
@@ -96,8 +101,8 @@ def _decode_kernel(tbl_ref, n_ref, q_ref, k_ref, v_ref, valid_ref,
         l_safe = jnp.where(l <= 0.0, 1.0, l)
         o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
         if cfg.weights_out:
-            mo_ref[0] = m_ref[...][:, 0]                  # (G,)
-            lo_ref[0] = l_ref[...][:, 0]                  # (G,)
+            mo_ref[0] = m_ref[...]                        # (G, 1)
+            lo_ref[0] = l_ref[...]                        # (G, 1)
 
 
 def _live_i(h, i, n_ref):
@@ -113,6 +118,26 @@ def _live_block(h, i, tbl_ref, n_ref):
     own arena; in ``shared_kv`` (paged) mode it is a pool page id into the
     one shared arena."""
     return tbl_ref[h, _live_i(h, i, n_ref)]
+
+
+def smem_table_bytes(bh: int, nb_tbl: int) -> int:
+    """SMEM bytes the two scalar-prefetched operands take on the TPU: the
+    (BHkv, NB_tbl) int32 table and the (BHkv,) live counts, with rows padded
+    to 8 and the table's columns to 128 (what the TPU compiler allocates)."""
+    rows = -(-bh // 8) * 8
+    return rows * (-(-nb_tbl // 128) * 128) * 4 + rows * 4
+
+
+def check_smem(bh: int, nb_tbl: int) -> None:
+    """Refuse a block table the chip's scalar memory cannot hold, with its
+    size, instead of the compiler's opaque out-of-SMEM error."""
+    need = smem_table_bytes(bh, nb_tbl)
+    if need > SMEM_BYTES - SMEM_RESERVE:
+        raise ValueError(
+            f"dms_decode block table ({bh} lane-heads x {nb_tbl} blocks, "
+            f"int32) needs {need} bytes of SMEM; the TPU has {SMEM_BYTES} "
+            f"({SMEM_RESERVE} kept for the compiler). Use a larger block_p "
+            f"or fewer lanes / a shorter arena.")
 
 
 def decode_fwd(q, k, v, valid, block_tbl, block_n, cfg: DecodeConfig):
@@ -143,15 +168,22 @@ def decode_fwd(q, k, v, valid, block_tbl, block_n, cfg: DecodeConfig):
     arena/pool capacity."""
     bh, g, dh = q.shape
     nb_tbl = block_tbl.shape[1]
+    bp = cfg.block_p
+    check_smem(bh, nb_tbl)
+    # TPU block rule: each block's last two dims must be (8, 128)-divisible
+    # or span the array's own last two dims.  So `valid` rides as
+    # (BHkv, NB, 1, block_p) and the per-row stats as (..., G, 1) — trailing
+    # dims a block covers whole.  Metadata-only reshapes, here and on return.
+    valid = valid.reshape(bh, -1, 1, bp)
 
     if cfg.shared_kv:
         # one shared arena: the leading axis is a singleton, the table entry
         # IS the page id; `valid` is table-ordered so it indexes by (h, i)
         kv_map = lambda h, i, tbl, n: (0, _live_block(h, i, tbl, n), 0)
-        val_map = lambda h, i, tbl, n: (h, _live_i(h, i, n))
+        val_map = lambda h, i, tbl, n: (h, _live_i(h, i, n), 0, 0)
     else:
         kv_map = lambda h, i, tbl, n: (h, _live_block(h, i, tbl, n), 0)
-        val_map = lambda h, i, tbl, n: (h, _live_block(h, i, tbl, n))
+        val_map = lambda h, i, tbl, n: (h, _live_block(h, i, tbl, n), 0, 0)
 
     out_specs = pl.BlockSpec((1, g, dh), lambda h, i, tbl, n: (h, 0, 0))
     out_shape = jax.ShapeDtypeStruct((bh, g, dh), q.dtype)
@@ -159,22 +191,21 @@ def decode_fwd(q, k, v, valid, block_tbl, block_n, cfg: DecodeConfig):
         # per-block outputs revisit the same (clamped) table row on the dead
         # tail — like the K/V inputs, a repeated index means no new copy; the
         # wrapper masks rows ≥ n so tail garbage never escapes.
-        wmap = lambda h, i, tbl, n: (h, _live_i(h, i, n), 0, 0)
-        mbmap = lambda h, i, tbl, n: (h, _live_i(h, i, n), 0)
-        stat = lambda h, i, tbl, n: (h, 0)
+        blk_map = lambda h, i, tbl, n: (h, _live_i(h, i, n), 0, 0)
+        stat = lambda h, i, tbl, n: (h, 0, 0)
         out_specs = [
             out_specs,
-            pl.BlockSpec((1, 1, g, cfg.block_p), wmap),
-            pl.BlockSpec((1, 1, g), mbmap),
-            pl.BlockSpec((1, g), stat),
-            pl.BlockSpec((1, g), stat),
+            pl.BlockSpec((1, 1, g, bp), blk_map),
+            pl.BlockSpec((1, 1, g, 1), blk_map),
+            pl.BlockSpec((1, g, 1), stat),
+            pl.BlockSpec((1, g, 1), stat),
         ]
         out_shape = [
             out_shape,
-            jax.ShapeDtypeStruct((bh, nb_tbl, g, cfg.block_p), jnp.float32),
-            jax.ShapeDtypeStruct((bh, nb_tbl, g), jnp.float32),
-            jax.ShapeDtypeStruct((bh, g), jnp.float32),
-            jax.ShapeDtypeStruct((bh, g), jnp.float32),
+            jax.ShapeDtypeStruct((bh, nb_tbl, g, bp), jnp.float32),
+            jax.ShapeDtypeStruct((bh, nb_tbl, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, g, 1), jnp.float32),
         ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -182,9 +213,9 @@ def decode_fwd(q, k, v, valid, block_tbl, block_n, cfg: DecodeConfig):
         grid=(bh, nb_tbl),
         in_specs=[
             pl.BlockSpec((1, g, dh), lambda h, i, tbl, n: (h, 0, 0)),
-            pl.BlockSpec((1, cfg.block_p, dh), kv_map),
-            pl.BlockSpec((1, cfg.block_p, dh), kv_map),
-            pl.BlockSpec((1, cfg.block_p), val_map),
+            pl.BlockSpec((1, bp, dh), kv_map),
+            pl.BlockSpec((1, bp, dh), kv_map),
+            pl.BlockSpec((1, 1, 1, bp), val_map),
         ],
         out_specs=out_specs,
         scratch_shapes=[
@@ -193,10 +224,14 @@ def decode_fwd(q, k, v, valid, block_tbl, block_n, cfg: DecodeConfig):
             pltpu.VMEM((g, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    res = pl.pallas_call(
         functools.partial(_decode_kernel, cfg=cfg),
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=cfg.interpret,
         name="dms_decode",
     )(block_tbl, block_n, q, k, v, valid)
+    if not cfg.weights_out:
+        return res
+    out, w_blk, m_blk, m_out, l_out = res
+    return (out, w_blk, m_blk[..., 0], m_out[..., 0], l_out[..., 0])

@@ -47,6 +47,12 @@ def _default_interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
+def kernel_by_default() -> bool:
+    """Whether serving decodes through this kernel when the caller does not
+    say: on the TPU yes (compiled), elsewhere the reference einsum."""
+    return jax.default_backend() == "tpu"
+
+
 def modeled_hbm_bytes(block_n, block_p: int, head_dim: int,
                       k_dtype, v_dtype) -> int:
     """K/V bytes the kernel fetches for one decode step: ``sum(n)`` live

@@ -146,8 +146,6 @@ def run_cell(arch_name, shape_name, *, multi_pod=False, policy_kind="vanilla",
                 scan_layers=False, attn_impl=attn_impl, accum_steps=1, tp=tp)
         compile_s = time.time() - t0
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):   # jax < 0.4.30 returned [dict]
-            cost = cost[0] if cost else {}
         if verbose:
             print(f"[{arch_name} × {shape_name} × {mesh_desc}] flops-pass "
                   f"compiled in {compile_s:.1f}s")
@@ -163,6 +161,7 @@ def run_cell(arch_name, shape_name, *, multi_pod=False, policy_kind="vanilla",
         report = roofline.analyze(
             compiled, arch=arch_name, shape=shape_name, mesh_desc=mesh_desc,
             num_devices=mesh.size, modeled=modeled,
+            device_kind=roofline.DRYRUN_DEVICE_KIND,
             model_flops=roofline.model_flops_for(arch, shape, shape.kind))
         rec = report.as_dict()
         rec["compile_seconds"] = compile_s

@@ -25,11 +25,23 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, Optional
 
-# TPU v5e (per chip)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # B/s
-ICI_BW = 50e9                # B/s per link
+# Per-chip peaks keyed by ``jax.Device.device_kind``.  Source: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+# inter-chip interconnect (four links, 50 GB/s each).
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+# the chip the production-mesh dry-run targets (launch/dryrun.py)
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
 POD_CHIPS = 256
+
+
+def device_peaks(kind: str) -> Dict[str, float]:
+    """Peaks of one chip of ``kind``; a kind not in the table is an error."""
+    if kind not in DEVICE_PEAKS:
+        raise KeyError(f"no peaks for device kind {kind!r}; known: "
+                       f"{sorted(DEVICE_PEAKS)}")
+    return DEVICE_PEAKS[kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -227,28 +239,27 @@ class RooflineReport:
 
 
 def analyze(compiled, *, arch: str, shape: str, mesh_desc: str, num_devices: int,
-            model_flops: float, hlo_text: Optional[str] = None,
+            model_flops: float, device_kind: str, hlo_text: Optional[str] = None,
             modeled: Optional[Dict[str, float]] = None) -> RooflineReport:
+    peaks = device_peaks(device_kind)
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()
     coll = parse_collectives(text, num_devices)
     modeled = modeled or {"total": byts}
 
-    compute_s = flops / PEAK_FLOPS
-    memory_s = byts / HBM_BW
-    memory_model_s = modeled["total"] / HBM_BW
-    collective_s = coll.wire_bytes / ICI_BW
+    compute_s = flops / peaks["flops"]
+    memory_s = byts / peaks["hbm_bw"]
+    memory_model_s = modeled["total"] / peaks["hbm_bw"]
+    collective_s = coll.wire_bytes / peaks["ici_bw"]
     terms = {"compute": compute_s, "memory": memory_model_s,
              "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
     step_time = max(terms.values())
     total_flops = flops * num_devices
     ratio = model_flops / total_flops if total_flops else 0.0
-    util = (model_flops / (num_devices * PEAK_FLOPS * step_time)
+    util = (model_flops / (num_devices * peaks["flops"] * step_time)
             if step_time > 0 else 0.0)
 
     ma = {}

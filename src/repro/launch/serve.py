@@ -1,10 +1,13 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> --policy dms``.
 
-Boots the engine with a smoke-scale model and serves synthetic requests
-through the continuous-batching scheduler: staggered arrivals, mixed prompt
-lengths, optional hyper-scaling width (shared-prefill fork) and EOS early
-exit.  Prints per-request budget metrics (prefill/decode KV reads, peak
-tokens) — the serving-side counterpart of the dry-run, runnable on CPU.
+Boots the engine with seed-0 random weights — the smoke-scale model by
+default, the published widths with ``--full`` — and serves synthetic
+requests through the continuous-batching scheduler: staggered arrivals,
+mixed prompt lengths, optional hyper-scaling width (shared-prefill fork) and
+EOS early exit.  Prints per-request budget metrics (prefill/decode KV reads,
+peak tokens).  Decode attention runs the Pallas kernel on TPU and the
+reference on CPU unless ``--use-kernel`` / ``--no-use-kernel`` says
+otherwise.  ``main`` returns ``(engine, scheduler, results)``.
 """
 from __future__ import annotations
 
@@ -14,9 +17,10 @@ import json
 import jax
 import numpy as np
 
-from repro.configs import get_smoke
+from repro.configs import get_arch, get_smoke
 from repro.core.config import KVPolicyConfig
 from repro.core.policy import available_policies
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import transformer as tfm
 from repro.serving import workload
 from repro.serving.engine import Engine
@@ -26,6 +30,10 @@ from repro.serving.scheduler import Request, SLOSpec
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen-r1-1.5b")
+    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="serve the published widths instead of the smoke "
+                         "config")
     ap.add_argument("--policy", default="dms",
                     choices=list(available_policies()))
     ap.add_argument("--cr", type=float, default=4.0)
@@ -40,7 +48,10 @@ def main(argv=None):
     ap.add_argument("--stagger", action="store_true",
                     help="staggered arrivals + mixed prompt lengths")
     ap.add_argument("--chunk", type=int, default=8)
-    ap.add_argument("--use-kernel", action="store_true")
+    ap.add_argument("--use-kernel", action=argparse.BooleanOptionalAction,
+                    default=None,
+                    help="decode through the Pallas kernel (default: on TPU "
+                         "only)")
     ap.add_argument("--prefix-cache-mb", type=float, default=0.0,
                     help="cross-request radix prefix cache host budget "
                          "(0 = off)")
@@ -115,7 +126,8 @@ def main(argv=None):
                          "newest arrivals are rejected (backpressure)")
     args = ap.parse_args(argv)
 
-    arch = get_smoke(args.arch)
+    setup_compile_cache()
+    arch = get_smoke(args.arch) if args.smoke else get_arch(args.arch)
     params = tfm.init_model(jax.random.PRNGKey(0), arch)
     policy = KVPolicyConfig(kind=args.policy, cr=args.cr, window=arch.dms.window,
                             paged=args.paged, pool_blocks=args.pool_blocks)
@@ -197,7 +209,7 @@ def main(argv=None):
               f"{'y' if r.degraded else '-':>4} {r.ttft_ticks:>5} "
               f"{r.tpot_ticks:>6.2f} {r.latency_ticks:>5}")
     print(json.dumps({
-        "policy": args.policy, "cr": args.cr,
+        "policy": args.policy, "cr": args.cr, "kernel": engine.use_kernel,
         "requests": len(results), "lanes": args.num_lanes,
         "scheduler_ticks": sched.ticks, "scheduler_steps": sched.steps,
     }))
@@ -207,6 +219,7 @@ def main(argv=None):
         print(json.dumps({"block_pool": pool}))
     if engine.prefix_cache is not None:
         print(json.dumps({"prefix_cache": engine.prefix_cache.stats()}))
+    return engine, sched, results
 
 
 if __name__ == "__main__":

@@ -66,8 +66,22 @@ def _init_block(key, arch: ArchConfig, kind: str, cross: bool = False) -> dict:
     return p
 
 
-def _stack(trees):
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
+def _stack_layers(n: int, make) -> Any:
+    """Stack the trees ``make(0) .. make(n-1)`` leaf by leaf, dropping each
+    leaf's per-layer arrays once its stacked copy exists: the peak is one
+    stacked leaf above the per-layer trees, not a second copy of them all
+    (at qwen-r1-1.5b width on a 16 GB chip, the difference between ~9 and
+    ~14.5 GB)."""
+    flat = [jax.tree_util.tree_flatten(make(s)) for s in range(n)]
+    treedef = flat[0][1]
+    per_layer = [leaves for leaves, _ in flat]
+    del flat
+    stacked = []
+    for j in range(len(per_layer[0])):
+        stacked.append(jnp.stack([leaves[j] for leaves in per_layer]))
+        for leaves in per_layer:
+            leaves[j] = None
+    return jax.tree_util.tree_unflatten(treedef, stacked)
 
 
 def init_model(key, arch: ArchConfig) -> dict:
@@ -85,9 +99,8 @@ def init_model(key, arch: ArchConfig) -> dict:
     blocks: Dict[str, Any] = {}
     for pi, kind in enumerate(arch.layer_pattern):
         layer_keys = jax.random.split(jax.random.fold_in(ks[2], pi), nsb)
-        blocks[str(pi)] = _stack([
-            _init_block(layer_keys[s], arch, kind, cross=arch.cross_attention)
-            for s in range(nsb)])
+        blocks[str(pi)] = _stack_layers(nsb, lambda s: _init_block(
+            layer_keys[s], arch, kind, cross=arch.cross_attention))
     params["blocks"] = blocks
 
     if arch.encoder_layers:
@@ -95,8 +108,8 @@ def init_model(key, arch: ArchConfig) -> dict:
         enc_blocks: Dict[str, Any] = {}
         for pi, kind in enumerate(arch.layer_pattern):
             layer_keys = jax.random.split(jax.random.fold_in(ks[3], pi), ne)
-            enc_blocks[str(pi)] = _stack([
-                _init_block(layer_keys[s], arch, kind) for s in range(ne)])
+            enc_blocks[str(pi)] = _stack_layers(
+                ne, lambda s: _init_block(layer_keys[s], arch, kind))
         params["enc_blocks"] = enc_blocks
         params["enc_final_norm"] = init_norm(arch.d_model, arch.norm)
     return params

@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.core.config import ArchConfig, KVPolicyConfig
 from repro.core.hyperscale import BudgetMeter, ScalingConfig, majority_vote
+from repro.kernels.dms_decode import ops as dkops
 from repro.models import transformer as tfm
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.scheduler import (Request, RequestResult, Scheduler,
@@ -53,16 +54,21 @@ _ENGINE_CACHE = object()
 
 class Engine:
     """Single-host engine; the same step functions lower onto the production
-    mesh (see launch/serve.py)."""
+    mesh (see launch/serve.py).
+
+    ``use_kernel=None`` follows the platform: the compiled Pallas decode
+    kernel on TPU, the reference attention elsewhere."""
 
     def __init__(self, arch: ArchConfig, params, policy: KVPolicyConfig,
-                 use_kernel: bool = False, temperature: float = 0.0,
+                 use_kernel: Optional[bool] = None, temperature: float = 0.0,
                  chunk: int = 8, prefix_cache_mb: float = 0.0,
                  prefix_cache_device_mb: float = 0.0,
                  export_policy: str = "always", export_stride: int = 1):
         self.arch = arch
         self.params = params
         self.policy = policy
+        if use_kernel is None:
+            use_kernel = dkops.kernel_by_default()
         self.use_kernel = use_kernel
         self.temperature = temperature
         self.chunk = chunk

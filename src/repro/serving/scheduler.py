@@ -1138,12 +1138,8 @@ class Scheduler:
         if poison is None:
             poison = np.zeros((b,), bool)
 
-        out = self._chunk_jit(
-            self.params, self.state, jnp.asarray(feed), jnp.asarray(feed_valid),
-            jnp.asarray(self.cur_tok), jnp.asarray(self.pos),
-            jnp.asarray(self.decoding), jnp.asarray(self.finished),
-            jnp.asarray(self.lane_eos), jnp.asarray(budget_left), self.rng,
-            jnp.asarray(poison))
+        out = self._chunk_jit(*self._chunk_args(feed, feed_valid,
+                                                budget_left, poison))
         (self.state, cur_tok, pos, finished, _, self.rng, last_logits,
          emitted, live, reads, act, bad) = out
         # the scheduler's ONE sanctioned host sync: once per chunk, never
@@ -1267,6 +1263,21 @@ class Scheduler:
                 doomed.setdefault(id(r), (r, "timeout"))
         for r, status in doomed.values():
             self._retire(r, status, results)
+
+    def _chunk_args(self, feed, feed_valid, budget_left, poison) -> tuple:
+        return (self.params, self.state, jnp.asarray(feed),
+                jnp.asarray(feed_valid), jnp.asarray(self.cur_tok),
+                jnp.asarray(self.pos), jnp.asarray(self.decoding),
+                jnp.asarray(self.finished), jnp.asarray(self.lane_eos),
+                jnp.asarray(budget_left), self.rng, jnp.asarray(poison))
+
+    def lower_chunk(self):
+        """Lower the chunk program with the argument types :meth:`_tick`
+        dispatches (idle lanes) — the exact program the scheduler serves."""
+        b, c = self.num_lanes, self.chunk
+        return self._chunk_jit.lower(*self._chunk_args(
+            np.zeros((b, c), np.int32), np.zeros((b, c), bool),
+            np.zeros((b,), np.int32), np.zeros((b,), bool)))
 
     def _pools_exhausted(self) -> bool:
         # reads only the per-pool exhausted scalars — part of the chunk's

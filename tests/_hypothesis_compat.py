@@ -1,35 +1,2 @@
-"""Graceful degradation when `hypothesis` is not installed.
-
-Property-based tests use the real library when available (see
-requirements-dev.txt); without it, each ``@given`` test degrades to a single
-pytest skip instead of erroring the whole collection — the rest of the suite
-still runs.
-"""
-try:
-    from hypothesis import given, settings, strategies as st  # noqa: F401
-
-    HAVE_HYPOTHESIS = True
-except ModuleNotFoundError:  # pragma: no cover - exercised only without the dep
-    import pytest
-
-    HAVE_HYPOTHESIS = False
-
-    class _AnyStrategy:
-        def __getattr__(self, name):
-            return lambda *a, **kw: None
-
-    st = _AnyStrategy()
-
-    def given(*_a, **_kw):
-        def deco(fn):
-            # zero-arg replacement: the strategy params must not be mistaken
-            # for pytest fixtures
-            def skipper():
-                pytest.skip("hypothesis not installed (see requirements-dev.txt)")
-            skipper.__name__ = fn.__name__
-            skipper.__doc__ = fn.__doc__
-            return skipper
-        return deco
-
-    def settings(*_a, **_kw):
-        return lambda fn: fn
+"""One import point for the property-test library (see requirements-dev.txt)."""
+from hypothesis import given, settings, strategies as st  # noqa: F401

@@ -120,6 +120,26 @@ def test_paper_archs_smoke(name):
     assert not bool(jnp.isnan(logits).any())
 
 
+def test_stack_layers_matches_stacking_all_at_once():
+    """Leaf-by-leaf stacking (per-layer arrays dropped as it goes) builds the
+    same tree, bit for bit and dtype for dtype, as stacking whole trees."""
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+
+    def make(s):
+        return {"w": jax.random.normal(keys[s], (4, 5)) * 0.02,
+                "norm": {"g": jnp.full((5,), s, jnp.bfloat16)}}
+
+    got = tfm._stack_layers(3, make)
+    want = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                  *[make(s) for s in range(3)])
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 @pytest.mark.parametrize("name", ASSIGNED_ARCHS)
 def test_full_configs_match_assignment(name):
     """The full configs carry the exact assigned hyper-parameters."""

@@ -12,9 +12,7 @@ positions, submit ticks) the continuous-batching scheduler must:
   lockstep oracle's totals.
 
 The checker is plain code shared by two drivers: a seeded deterministic
-test (always runs, also under the no-hypothesis shim) and a hypothesis
-``@given`` fuzzer (runs when hypothesis is installed; degrades to a skip
-via ``tests/_hypothesis_compat``).
+test and a hypothesis ``@given`` fuzzer.
 
 The chaos half of the file turns the same oracle discipline on the failure
 path: random :class:`~repro.serving.faults.FaultPlan` schedules (pool
@@ -63,8 +61,7 @@ def _prime(arch, params) -> None:
 
 def _engine() -> Engine:
     if "eng" not in _CTX:
-        # fuzz driver ran without the seeded tests (it cannot take pytest
-        # fixtures under the no-hypothesis shim): build the model directly
+        # the fuzz test ran without the seeded tests: build the model directly
         arch = get_smoke("qwen-r1-1.5b")
         arch = dataclasses.replace(
             arch, dms=dataclasses.replace(arch.dms, window=4, target_cr=4.0))
@@ -160,9 +157,8 @@ def _spec_from_rng(rng, n):
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_random_trace_matches_solo_oracle_seeded(seed, tiny_arch, tiny_params):
-    """Deterministic driver — runs in every environment, shim included.
-    Reuses the session tiny model from conftest (the fuzz driver below can't
-    take fixtures under the shim, so it primes itself only when run alone)."""
+    """Deterministic case.  Reuses the shared tiny model from conftest (the
+    fuzz test below primes itself only when run alone)."""
     _prime(tiny_arch, tiny_params)
     rng = np.random.default_rng(seed)
     check_trace(_spec_from_rng(rng, n=int(rng.integers(2, 5))))

@@ -5,8 +5,7 @@ The determinism contract is the whole point of ``repro.serving.workload``:
 same seed ⇒ bit-identical ``Request`` trace (uids, arrivals, prompts,
 budgets, widths), so tests, benchmarks, and the chaos harness replay the
 exact traffic they were calibrated on.  The checker is plain code shared by
-a seeded deterministic driver and a hypothesis ``@given`` fuzzer (degrades
-to a skip via ``tests/_hypothesis_compat``).
+a seeded deterministic test and a hypothesis ``@given`` fuzzer.
 """
 import numpy as np
 import pytest
