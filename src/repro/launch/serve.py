@@ -199,15 +199,19 @@ def main(argv=None):
             "latency_ticks": r.latency_ticks,
             "ttft_ticks": r.ttft_ticks,
             "tpot_ticks": round(r.tpot_ticks, 4),
+            "ttft_s": r.ttft_s,
+            "tpot_s": r.tpot_s,
         }))
     # per-request TTFT/TPOT/status summary table (human-scan view of the
-    # JSON rows above)
+    # JSON rows above); seconds are the scheduler's clock from submit()
+    sec = lambda v: "-" if v is None else f"{v:.4f}"
     print(f"# {'uid':>4} {'status':>9} {'deg':>4} {'ttft':>5} "
-          f"{'tpot':>6} {'lat':>5}")
+          f"{'tpot':>6} {'lat':>5} {'ttft_s':>9} {'tpot_s':>9}")
     for r in sorted(results, key=lambda r: r.uid):
         print(f"# {r.uid:>4} {r.status:>9} "
               f"{'y' if r.degraded else '-':>4} {r.ttft_ticks:>5} "
-              f"{r.tpot_ticks:>6.2f} {r.latency_ticks:>5}")
+              f"{r.tpot_ticks:>6.2f} {r.latency_ticks:>5} "
+              f"{sec(r.ttft_s):>9} {sec(r.tpot_s):>9}")
     print(json.dumps({
         "policy": args.policy, "cr": args.cr, "kernel": engine.use_kernel,
         "requests": len(results), "lanes": args.num_lanes,

@@ -436,10 +436,15 @@ def decode_attention(
                "arch": arch, "dtype": dtype, "active": active,
                "layer_salt": jax.lax.bitcast_convert_type(
                    p["wo"].reshape(-1)[0].astype(jnp.float32), jnp.uint32)}
-    inner, spec = pol.decode_update(cache.cache, q, k_new_c, v_new_c, pol_aux)
-    out, w_group, impl = _masked_decode(
-        q, spec, window if spec.positions is not None else None, cfg,
-        use_kernel, pos_lane, need_weights=spec.needs_weights)
+    # named scopes split the chunk program's device time by layer in a
+    # profiler trace (HLO metadata only: the compiled ops are unchanged)
+    with jax.named_scope("kv_write"):
+        inner, spec = pol.decode_update(cache.cache, q, k_new_c, v_new_c,
+                                        pol_aux)
+    with jax.named_scope("attend"):
+        out, w_group, impl = _masked_decode(
+            q, spec, window if spec.positions is not None else None, cfg,
+            use_kernel, pos_lane, need_weights=spec.needs_weights)
     if spec.needs_weights:
         inner = pol.post_attend(inner, w_group, active=active)
     cache = dataclasses.replace(cache, cache=inner)

@@ -584,7 +584,9 @@ def decode_step(
                     x_t = x_t + c_out
                 if arch.mlp is not None:
                     h = norm_apply(p["mlp_norm"], x_t, arch.norm, arch.norm_eps)
-                    m_out, _ = mlp_apply(p["mlp"], h, arch.mlp, jnp.dtype(arch.dtype))
+                    with jax.named_scope("mlp"):
+                        m_out, _ = mlp_apply(p["mlp"], h, arch.mlp,
+                                             jnp.dtype(arch.dtype))
                     if arch.post_norm:
                         m_out = norm_apply(p["mlp_post_norm"], m_out, arch.norm, arch.norm_eps)
                     x_t = x_t + m_out
@@ -598,7 +600,9 @@ def decode_step(
                 x_t = x_t + r_out
                 if arch.mlp is not None:
                     h = norm_apply(p["mlp_norm"], x_t, arch.norm, arch.norm_eps)
-                    m_out, _ = mlp_apply(p["mlp"], h, arch.mlp, jnp.dtype(arch.dtype))
+                    with jax.named_scope("mlp"):
+                        m_out, _ = mlp_apply(p["mlp"], h, arch.mlp,
+                                             jnp.dtype(arch.dtype))
                     x_t = x_t + m_out
             new_caches[str(pi)] = new_c
         return (x_t, live, reads), new_caches
@@ -620,9 +624,11 @@ def decode_step(
         (x, live, reads) = carry
         new_state = jax.tree_util.tree_map(lambda *zs: jnp.stack(zs), *outs)
     if active is not None:
-        new_state = lane_select(active, new_state, state)
+        with jax.named_scope("lane_select"):
+            new_state = lane_select(active, new_state, state)
         reads = reads * active.astype(reads.dtype)
-    logits = lm_logits(params, x, arch)[:, 0]
+    with jax.named_scope("sample"):
+        logits = lm_logits(params, x, arch)[:, 0]
     # static int (i32 under jit, lint-clean): 1 iff every attention layer
     # traced the Pallas kernel — a requested kernel that silently fell back
     # to the reference einsum is visible in the step metrics

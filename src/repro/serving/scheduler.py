@@ -83,11 +83,23 @@ replaces the lockstep fixed batch with a real scheduler:
   percentiles, and queue-depth / lane-utilization timelines;
   ``serving/workload.py`` generates the traffic, ``benchmarks/slo_harness.py``
   gates the goodput win over the uncontrolled baseline.
+* **Tick-loop tracing.**  Each run-loop iteration is one ``sched.tick``
+  profiler span (``jax.profiler.TraceAnnotation``, so it lands on the
+  device trace's clock) holding its phases — ``sched.admit``,
+  ``sched.prepare``, ``sched.dispatch``, ``sched.readback``, ``sched.book``
+  (with ``sched.reclaim`` inside) — and one :class:`TickRecord` in
+  :attr:`Scheduler.tick_log`, stamped with :attr:`Scheduler.clock` as each
+  phase starts.  Always on: with the profiler off a span costs a TraceMe
+  check, and no phase adds a device sync.  docs/serving.md "Tick-loop
+  observability" names the spans and the chunk program's scopes.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
 import math
+import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -171,7 +183,10 @@ class RequestResult:
     post-first token (0.0 for single-token generations).  ``degraded`` marks
     a hyper-scaling request served at reduced width by the overload ladder —
     ``tokens`` then has the *served* width's rows and equals a solo run at
-    that width."""
+    that width.  ``submitted_s``/``admitted_s``/``first_token_s``/
+    ``finished_s`` are the scheduler's :attr:`~Scheduler.clock` at
+    :meth:`~Scheduler.submit` and at the points the matching ticks are
+    taken (None where the event never happened)."""
 
     uid: int
     tokens: np.ndarray            # (W, max_new) int32, padded after EOS
@@ -188,6 +203,58 @@ class RequestResult:
     ttft_ticks: int = -1
     tpot_ticks: float = 0.0
     degraded: bool = False
+    submitted_s: Optional[float] = None
+    admitted_s: Optional[float] = None
+    first_token_s: Optional[float] = None
+    finished_s: Optional[float] = None
+
+    @property
+    def ttft_s(self) -> Optional[float]:
+        """Submission → first sampled token, in clock seconds."""
+        if self.first_token_s is None or self.submitted_s is None:
+            return None
+        return self.first_token_s - self.submitted_s
+
+    @property
+    def tpot_s(self) -> Optional[float]:
+        """Seconds per post-first token (0.0 for single-token generations,
+        None when no token was sampled or the request has not finished)."""
+        if self.first_token_s is None or self.finished_s is None:
+            return None
+        gen = int(self.lengths.max()) if len(self.lengths) else 0
+        return ((self.finished_s - self.first_token_s) / (gen - 1)
+                if gen > 1 else 0.0)
+
+
+#: rows :attr:`Scheduler.tick_log` keeps; older ticks drop off the front
+TICK_LOG_ROWS = 4096
+
+
+@dataclass
+class TickRecord:
+    """One run-loop iteration (one tick) on :attr:`Scheduler.clock`.
+
+    Each stamp is taken as its phase starts; None where the phase did not
+    run (an idle tick, or one whose lanes were all preempted, dispatches
+    nothing): ``start`` (``sched.tick``: admission begins), ``prepare``
+    (``sched.prepare``), ``dispatch`` (the chunk program is called),
+    ``returned`` (the call returned: the work is enqueued, the readback
+    begins), ``ready`` (the host arrays are in hand, ``sched.book``
+    begins) and ``end``.  ``active_lanes`` counts lanes the chunk stepped,
+    ``prefill_lanes`` lanes it fed prompt tokens, and ``tokens`` the output
+    tokens added to chains since the last logged row (first tokens sampled
+    at a fork included), so the log's tokens add up to the results'."""
+
+    tick: int
+    start: float
+    prepare: Optional[float] = None
+    dispatch: Optional[float] = None
+    returned: Optional[float] = None
+    ready: Optional[float] = None
+    end: Optional[float] = None
+    active_lanes: int = 0
+    prefill_lanes: int = 0
+    tokens: int = 0
 
 
 class _ReqState:
@@ -205,6 +272,9 @@ class _ReqState:
         self.pad_id = pad_id
         self.admitted_tick = -1                # -1 = never admitted
         self.first_token_tick = -1             # -1 = no token ever sampled
+        self.submitted_s: Optional[float] = None   # clock stamps (seconds)
+        self.admitted_s: Optional[float] = None
+        self.first_token_s: Optional[float] = None
         self.status = "ok"
         self.preempt_count = 0
         self.resume_at = 0                     # backoff: earliest re-admission
@@ -226,7 +296,8 @@ class _ReqState:
         self.chains = [[] for _ in range(width)]
         self.chain_done = [False] * width
 
-    def result(self, peak_bytes: float, finished_tick: int) -> RequestResult:
+    def result(self, peak_bytes: float, finished_tick: int,
+               finished_s: Optional[float] = None) -> RequestResult:
         w, m = self.width, self.req.max_new
         toks = np.full((w, m), self.pad_id, np.int32)
         lens = np.zeros((w,), np.int32)
@@ -248,7 +319,9 @@ class _ReqState:
             latency_ticks=max(0, finished_tick - self.req.arrival),
             first_token_tick=ft,
             ttft_ticks=ft - self.req.arrival if ft >= 0 else -1,
-            tpot_ticks=float(tpot), degraded=self.width < self.req.width)
+            tpot_ticks=float(tpot), degraded=self.width < self.req.width,
+            submitted_s=self.submitted_s, admitted_s=self.admitted_s,
+            first_token_s=self.first_token_s, finished_s=finished_s)
 
 
 def make_chunk_fn(arch, *, use_kernel: bool = False,
@@ -284,11 +357,13 @@ def make_chunk_fn(arch, *, use_kernel: bool = False,
             # bitwise-unchanged.
             logits = jnp.where(poison[:, None], jnp.float32(jnp.nan), logits)
             bad = bad | (active & ~jnp.all(jnp.isfinite(logits), axis=-1))
-            if temperature > 0.0:
-                nxt = jax.random.categorical(sub, logits / temperature, axis=-1)
-            else:
-                nxt = jnp.argmax(logits, axis=-1)
-            nxt = nxt.astype(jnp.int32)
+            with jax.named_scope("sample"):
+                if temperature > 0.0:
+                    nxt = jax.random.categorical(sub, logits / temperature,
+                                                 axis=-1)
+                else:
+                    nxt = jnp.argmax(logits, axis=-1)
+                nxt = nxt.astype(jnp.int32)
             emitted = jnp.where(decode_now, nxt, -1)
             cur_tok = jnp.where(decode_now, nxt, cur_tok)
             finished = finished | (decode_now & (lane_eos >= 0)
@@ -366,6 +441,12 @@ class Scheduler:
         self._timeline: Dict[str, List[int]] = {"queue_depth": [],
                                                 "active_lanes": []}
         self._hot_until = -1           # throttle hysteresis: degrade before it
+        # tick-loop tracing: the clock every stamp reads, the last
+        # TICK_LOG_ROWS ticks, and the row the current iteration stamps
+        self.clock: Callable[[], float] = time.perf_counter
+        self.tick_log: "collections.deque[TickRecord]" = collections.deque(
+            maxlen=TICK_LOG_ROWS)
+        self._row = TickRecord(0, self.clock())
         self._chunk_jit = chunk_jit or jax.jit(make_chunk_fn(
             arch, use_kernel=use_kernel, temperature=temperature))
         self._reset_jit = reset_jit or jax.jit(self._reset_fn,
@@ -444,7 +525,9 @@ class Scheduler:
                     f"{req.width * d} blocks exceeds pool {i} capacity "
                     f"{self._pool_descs[i][3]} — unservable at any load")
         self.offered += 1
-        self.queue.append(_ReqState(req, self.pad_id))
+        st = _ReqState(req, self.pad_id)
+        st.submitted_s = self.clock()
+        self.queue.append(st)
 
     def pool_stats(self) -> Optional[Dict[str, Any]]:
         """Paged-pool observability: live/free/allocated blocks, CoW share
@@ -494,28 +577,47 @@ class Scheduler:
         failed out rather than spun on (see :meth:`_starved`)."""
         results: List[RequestResult] = []
         while self.queue or self.active_reqs:
-            if self.faults is not None:
-                self.faults.on_tick(self, results)
-            self._expire_queued(results)
-            self._bound_queue(results)
-            self._shed_queued(results)
-            # fork before admitting: freed lanes must reach held hyperscale
-            # requests before new admissions can take them
-            self._fork_ready()
-            self._admit()
-            self._fork_ready()
-            if not any(o is not None for o in self.owner):
-                if not self.queue and not self.active_reqs:
-                    break
-                if self._starved():
-                    self._fail_starved(results)
-                    continue
-                # nothing admitted yet (future arrivals / backoff): tick time
-                self._record_timeline()
-                self.ticks += 1
-                continue
-            self._tick(results)
+            # a row left unlogged (a pause raised mid-iteration) hands its
+            # tokens on, so the log's tokens add up to the results'
+            self._row = TickRecord(self.ticks, self.clock(), tokens=(
+                self._row.tokens if self._row.end is None else 0))
+            with self._phase("sched.tick"):
+                with self._phase("sched.admit"):
+                    if self.faults is not None:
+                        self.faults.on_tick(self, results)
+                    self._expire_queued(results)
+                    self._bound_queue(results)
+                    self._shed_queued(results)
+                    # fork before admitting: freed lanes must reach held
+                    # hyperscale requests before new admissions can take them
+                    self._fork_ready()
+                    self._admit()
+                    self._fork_ready()
+                if not any(o is not None for o in self.owner):
+                    if not self.queue and not self.active_reqs:
+                        break
+                    if self._starved():
+                        self._fail_starved(results)
+                        continue
+                    # nothing admitted yet (future arrivals / backoff): tick
+                    # time passes with no dispatch
+                    self._record_timeline()
+                    self.ticks += 1
+                else:
+                    self._tick(results)
+            self._row.end = self.clock()
+            self.tick_log.append(self._row)
         return results
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, stamp: Optional[str] = None):
+        """One phase of the tick loop: a profiler span named ``name`` and,
+        with ``stamp``, the current :class:`TickRecord`'s field of that name
+        set to :attr:`clock` as the phase starts."""
+        if stamp is not None:
+            setattr(self._row, stamp, self.clock())
+        with jax.profiler.TraceAnnotation(name):
+            yield
 
     # -- lifecycle stages --------------------------------------------------
 
@@ -632,6 +734,7 @@ class Scheduler:
             self.chain_of[lane] = 0
             nxt.lanes = [lane]
             nxt.admitted_tick = self.ticks
+            nxt.admitted_s = self.clock()
             self.active_reqs.append(nxt)
             self.pos[lane] = 0
             self.decoding[lane] = False
@@ -759,7 +862,7 @@ class Scheduler:
                 peak_bytes: float) -> None:
         """Single choke point for retiring a request: the result goes to the
         caller AND onto the ledger :meth:`slo_stats` aggregates."""
-        res = r.result(peak_bytes, self.ticks)
+        res = r.result(peak_bytes, self.ticks, self.clock())
         results.append(res)
         self._finished.append(res)
 
@@ -1092,6 +1195,8 @@ class Scheduler:
             first = np.asarray(first, np.int32)
         if r.first_token_tick < 0:
             r.first_token_tick = self.ticks    # TTFT endpoint
+            r.first_token_s = self.clock()
+        self._row.tokens += w
         r.decode_meter.observe_step([0.0], new_tokens=w,
                                     reads_tokens_per_layer=[0.0])
         for c, lane in enumerate(r.lanes):
@@ -1105,62 +1210,83 @@ class Scheduler:
         r.hold_logits = None
 
     def _tick(self, results: List[RequestResult]) -> None:
-        self._record_timeline()
-        # preemptive pressure relief BEFORE dispatch: post-hoc preemption
-        # cannot be bitwise (writes were already dropped mid-chunk), so the
-        # margin check runs at the boundary, where snapshots are still exact
-        if self.on_pressure == "preempt" and self._pool_descs \
-                and self._pressure_possible():
-            self._relieve_pressure(results)
-            if not self.active_reqs:
-                self.ticks += 1        # everything evicted: time still passes
-                return
-        b, c = self.num_lanes, self.chunk
-        feed = np.zeros((b, c), np.int32)
-        feed_valid = np.zeros((b, c), bool)
-        budget_left = np.zeros((b,), np.int32)
-        prefill_take: Dict[int, int] = {}
-        for lane in range(b):
-            r = self.owner[lane]
-            if r is None:
-                continue
-            if self.decoding[lane]:
-                budget_left[lane] = r.req.max_new - len(
-                    r.chains[self.chain_of[lane]])
-            elif r.hold_logits is None and lane == r.lanes[0]:
-                take = min(c, len(r.req.prompt) - r.consumed)
-                if take > 0:
-                    feed[lane, :take] = r.req.prompt[r.consumed:r.consumed + take]
-                    feed_valid[lane, :take] = True
-                    prefill_take[lane] = take
-        poison = (self.faults.poison(self.ticks, b)
-                  if self.faults is not None else None)
-        if poison is None:
-            poison = np.zeros((b,), bool)
+        with self._phase("sched.prepare", "prepare"):
+            self._record_timeline()
+            # preemptive pressure relief BEFORE dispatch: post-hoc preemption
+            # cannot be bitwise (writes were already dropped mid-chunk), so
+            # the margin check runs at the boundary, where snapshots are
+            # still exact
+            if self.on_pressure == "preempt" and self._pool_descs \
+                    and self._pressure_possible():
+                self._relieve_pressure(results)
+                if not self.active_reqs:
+                    self.ticks += 1    # everything evicted: time still passes
+                    return
+            b, c = self.num_lanes, self.chunk
+            feed = np.zeros((b, c), np.int32)
+            feed_valid = np.zeros((b, c), bool)
+            budget_left = np.zeros((b,), np.int32)
+            prefill_take: Dict[int, int] = {}
+            for lane in range(b):
+                r = self.owner[lane]
+                if r is None:
+                    continue
+                if self.decoding[lane]:
+                    budget_left[lane] = r.req.max_new - len(
+                        r.chains[self.chain_of[lane]])
+                elif r.hold_logits is None and lane == r.lanes[0]:
+                    take = min(c, len(r.req.prompt) - r.consumed)
+                    if take > 0:
+                        feed[lane, :take] = r.req.prompt[
+                            r.consumed:r.consumed + take]
+                        feed_valid[lane, :take] = True
+                        prefill_take[lane] = take
+            poison = (self.faults.poison(self.ticks, b)
+                      if self.faults is not None else None)
+            if poison is None:
+                poison = np.zeros((b,), bool)
+            args = self._chunk_args(feed, feed_valid, budget_left, poison)
 
-        out = self._chunk_jit(*self._chunk_args(feed, feed_valid,
-                                                budget_left, poison))
-        (self.state, cur_tok, pos, finished, _, self.rng, last_logits,
-         emitted, live, reads, act, bad) = out
-        # the scheduler's ONE sanctioned host sync: once per chunk, never
-        # per step (the host-sync tripwire in repro.analysis enforces this).
-        # The failure tripwires ride the same boundary: the chunk's latched
-        # bad-logit mask and the pool's exhausted latch are chunk outputs,
-        # not extra stalls.
-        with sanctioned("tick-boundary"):
-            self.cur_tok = np.array(cur_tok)   # writable host copies
-            self.pos = np.array(pos)
-            self.finished = np.array(finished)
-            emitted = np.asarray(emitted)      # (C, B)
-            live = np.asarray(live)
-            reads = np.asarray(reads)
-            act = np.asarray(act)
-            bad = np.asarray(bad)              # (B,) non-finite logits seen
-            exhausted = (self._pools_exhausted()
-                         if self._pool_descs and self.on_pressure != "ignore"
-                         else False)
+        with self._phase("sched.dispatch", "dispatch"):
+            out = self._chunk_jit(*args)
+            # the dispatched (old) decode state must not outlive the call:
+            # kept through booking, a lane reset would hold a third state
+            del args
+        with self._phase("sched.readback", "returned"):
+            (self.state, cur_tok, pos, finished, _, self.rng, last_logits,
+             emitted, live, reads, act, bad) = out
+            # the scheduler's ONE sanctioned host sync: once per chunk, never
+            # per step (the host-sync tripwire in repro.analysis enforces
+            # this).  The failure tripwires ride the same boundary: the
+            # chunk's latched bad-logit mask and the pool's exhausted latch
+            # are chunk outputs, not extra stalls.
+            with sanctioned("tick-boundary"):
+                self.cur_tok = np.array(cur_tok)   # writable host copies
+                self.pos = np.array(pos)
+                self.finished = np.array(finished)
+                emitted = np.asarray(emitted)      # (C, B)
+                live = np.asarray(live)
+                reads = np.asarray(reads)
+                act = np.asarray(act)
+                bad = np.asarray(bad)              # (B,) non-finite logits
+                exhausted = (self._pools_exhausted()
+                             if self._pool_descs
+                             and self.on_pressure != "ignore" else False)
+        with self._phase("sched.book", "ready"):
+            self._book(results, prefill_take, last_logits, emitted, live,
+                       reads, act, bad, exhausted)
+
+    def _book(self, results: List[RequestResult],
+              prefill_take: Dict[int, int], last_logits, emitted, live,
+              reads, act, bad, exhausted: bool) -> None:
+        """Book a read-back chunk: advance the clock, fail doomed requests,
+        meter, complete prefills, collect tokens, reclaim finished lanes and
+        enforce deadlines.  ``last_logits`` is still on the device."""
+        b, c = self.num_lanes, self.chunk
         self.ticks += 1
         self.steps += c
+        self._row.active_lanes = int(act.any(axis=0).sum())
+        self._row.prefill_lanes = len(prefill_take)
 
         # failure semantics, decided BEFORE token/hold collection: a doomed
         # request keeps nothing from a corrupt or poisoned chunk
@@ -1226,6 +1352,7 @@ class Scheduler:
                 tok = emitted[t, lane]
                 if tok >= 0:
                     chain.append(int(tok))
+                    self._row.tokens += 1
             if self.finished[lane] or len(chain) >= r.req.max_new:
                 r.chain_done[self.chain_of[lane]] = True
                 self.finished[lane] = True
@@ -1249,7 +1376,8 @@ class Scheduler:
                     self.decoding[lane] = False
                     self.finished[lane] = False
                     self.pos[lane] = 0
-            self._reset(reclaim)
+            with self._phase("sched.reclaim"):
+                self._reset(reclaim)
 
         # deadlines: completion above wins a tie; anything still active past
         # its deadline times out now (definite status, lanes reclaimed).

@@ -57,7 +57,10 @@ def test_serve_main_returns_engine_scheduler_results(monkeypatch, capsys,
     assert all(r.status == "ok" for r in results)
     assert all(int(r.lengths[0]) == 3 for r in results)
     assert sched.ticks > 0
-    assert f'"kernel": {str(want).lower()}' in capsys.readouterr().out
+    assert all(0 < r.ttft_s <= r.finished_s - r.submitted_s for r in results)
+    out = capsys.readouterr().out
+    assert f'"kernel": {str(want).lower()}' in out
+    assert '"ttft_s": ' in out and '"tpot_s": ' in out
 
 
 def test_device_peaks_known_kind_and_unknown_refused():
