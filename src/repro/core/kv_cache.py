@@ -107,8 +107,8 @@ def _tree_dataclass(cls):
 class BlockTable:
     """Per-(lane, kv-head) compacted index table of *live* KV blocks.
 
-    The flash-decode kernel grids over this table instead of the raw arena:
-    its scalar-prefetched entries drive the K/V block index maps, so blocks
+    The flash-decode kernel walks this table instead of the raw arena: its
+    scalar-prefetched entries drive the kernel's K/V block copies, so blocks
     with zero live slots are never DMA'd — decode HBM traffic scales with
     live tokens, not arena capacity (see docs/kernels.md).
 

@@ -82,8 +82,8 @@ class AttendSpec:
     with the flash-decode kernel (docs/kernels.md): ``block_tbl`` (B, Hkv,
     NB) int32 lists the arena's live ``block_p``-sized K/V blocks per (lane,
     kv head), compacted into the first ``block_n`` (B, Hkv) entries.  The
-    kernel's scalar-prefetched index maps stream exactly those blocks, so
-    decode HBM traffic scales with live tokens instead of arena capacity.
+    kernel's scalar-prefetched table drives its copies of exactly those
+    blocks, so decode HBM traffic scales with live tokens instead of arena capacity.
     Every *visible* slot must be covered by a listed block (a listed block
     may still contain dead slots — the kernel masks those via ``visible``);
     ``block_p == 0`` means "no table" and the kernel falls back to streaming

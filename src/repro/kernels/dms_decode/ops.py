@@ -56,9 +56,9 @@ def kernel_by_default() -> bool:
 def modeled_hbm_bytes(block_n, block_p: int, head_dim: int,
                       k_dtype, v_dtype) -> int:
     """K/V bytes the kernel fetches for one decode step: ``sum(n)`` live
-    blocks × block bytes.  Exact by construction — the index maps fetch
-    precisely the first ``n`` table entries per (lane, kv head), and the
-    clamped tail re-uses the last block's buffer (no DMA).  The benchmark's
+    blocks × block bytes.  Exact by construction — the kernel copies
+    precisely the first ``n`` table entries per (lane, kv head), one block of
+    K and one of V each, and nothing past them.  The benchmark's
     traffic model (``benchmarks/decode_path.py``) asserts this scales with
     live tokens, not arena capacity."""
     per_slot = head_dim * (jnp.dtype(k_dtype).itemsize
@@ -117,8 +117,9 @@ def dms_decode_attention(
             tblf = jnp.clip(ptbl, 0, npool - 1).reshape(b * hkv, -1)
             kf = pool_k.reshape(1, npool * bp, dh)
             vf = pool_v.reshape(1, npool * bp, dh)
-            # valid rides pre-gathered into table order so its index map
-            # needs no indirection inside the kernel (bool rows — cheap)
+            # valid rides pre-gathered into table order so the kernel reads
+            # its rows by table entry, with no second indirection (bool
+            # rows — cheap)
             valf = jnp.take_along_axis(
                 valid.reshape(b, hkv, p // bp, bp),
                 jnp.clip(block_tbl, 0, p // bp - 1)[..., None], axis=2,

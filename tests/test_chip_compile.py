@@ -92,11 +92,21 @@ def _compile_kernel(args, mode, block_p):
     return jax.jit(fixed).lower(*base).compile()
 
 
-@pytest.mark.parametrize("mode", ["fixed", "paged", "weights_out"])
-def test_kernel_compiles_for_v5e(one_chip, compiled_kernel, mode):
-    args = _kernel_args(one_chip, LANES, 336, 16)
+MODES = ["fixed", "paged", "weights_out"]
+# (mode, lanes, arena slots a lane): a small arena, then the two benchmark
+# cells' shapes — dms-cr8 (80 lanes x 848 slots) and vanilla (16 x 4608)
+KERNEL_CASES = [pytest.param(m, LANES, 336, id=m) for m in MODES] + [
+    pytest.param(m, lanes, arena, id=f"{m}-{cell}") for cell, lanes, arena in
+    (("dms-cr8", 80, 848), ("vanilla", 16, 4608)) for m in MODES]
+
+
+@pytest.mark.parametrize("mode,lanes,arena", KERNEL_CASES)
+def test_kernel_compiles_for_v5e(one_chip, compiled_kernel, mode, lanes,
+                                 arena):
+    args = _kernel_args(one_chip, lanes, arena, 16)
     text = _compile_kernel(args, mode, 16).as_text()
     assert 'custom_call_target="tpu_custom_call"' in text
+    # the benchmark finds the kernel's time in the trace by this name
     assert "dms_decode" in text
 
 

@@ -145,27 +145,133 @@ def test_decode_kernel_all_blocks_dead_but_one():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("shape", [(2, 4, 2, 64, 16), (1, 8, 1, 96, 32),
-                                   (2, 12, 2, 32, 8)])
+# (B, Hq, Hkv, P, Dh), optionally with the case's options: `bp` block_p
+# (C = 128 // bp entries a pass), `n` per-(lane, head) live counts over an
+# unordered table, `paged`, `weights`, `cap`
+_BT = (1, 4, 2, 256, 16)
+BLOCK_TABLE_CASES = [
+    (2, 4, 2, 64, 16), (1, 8, 1, 96, 32), (2, 12, 2, 32, 8),
+    pytest.param(_BT + (dict(n=[[0, 16]]),), id="n0-and-full"),
+    pytest.param(_BT + (dict(n=[[11, 3]]),), id="n-off-pass"),
+    pytest.param((1, 4, 2, 208, 16, dict(n=[[13, 9]])), id="n-full-table-off-pass"),
+    pytest.param(_BT + (dict(n=[[12, 7]], paged=True),), id="paged"),
+    pytest.param(_BT + (dict(n=[[12, 0]], weights=True),), id="weights"),
+    pytest.param(_BT + (dict(n=[[12, 7]], paged=True, weights=True),),
+                 id="paged-weights"),
+    pytest.param(_BT + (dict(n=[[12, 7]], cap=1.5),), id="logit-cap"),
+    pytest.param(_BT + (dict(bp=128, n=[[2, 1]]),), id="c1"),
+    pytest.param(_BT + (dict(bp=64, n=[[3, 4]]),), id="c2"),
+    pytest.param(_BT + (dict(bp=8, n=[[21, 32]]),), id="c16"),
+]
+
+
+def _unordered_table(key, b, hkv, nb, n):
+    """Per head a random order of every block, the first ``n`` listed: what
+    swap-removal leaves (unordered, stale ids past ``n``)."""
+    keys = jax.random.split(key, b * hkv)
+    tbl = jnp.stack([jax.random.permutation(k_, nb) for k_ in keys])
+    tbl = tbl.reshape(b, hkv, nb).astype(jnp.int32)
+    n = jnp.asarray(n, jnp.int32)
+    listed = jnp.zeros((b, hkv, nb), bool).at[
+        jnp.arange(b)[:, None, None], jnp.arange(hkv)[None, :, None],
+        tbl].set(jnp.arange(nb)[None, None] < n[..., None])
+    return tbl, n, listed
+
+
+def _paged_args(key, k, v, bp):
+    """Scatter the arena's blocks to random pages of one shared pool."""
+    b, hkv, p, dh = k.shape
+    nb = p // bp
+    phys = jax.random.permutation(key, b * hkv * nb).astype(jnp.int32)
+    pool = lambda a: jnp.zeros((b * hkv * nb, bp, dh), a.dtype).at[phys].set(
+        a.reshape(-1, bp, dh))
+    return dict(pool_k=pool(k), pool_v=pool(v), phys=phys.reshape(b, hkv, nb))
+
+
+def _ref_weights(q, k, vis, cap=None):
+    """Post-softmax weights over the visible slots, summed over each kv
+    head's query group: (B, Hkv, P)."""
+    b, _, hq, dh = q.shape
+    hkv = k.shape[1]
+    qg = q[:, 0].reshape(b, hkv, hq // hkv, dh).astype(jnp.float32)
+    s = jnp.einsum("bhgd,bhpd->bhgp", qg, k.astype(jnp.float32)) * dh ** -0.5
+    if cap is not None:
+        s = cap * jnp.tanh(s / cap)
+    s = jnp.where(vis[:, :, None], s, -1e30)
+    return jnp.sum(jax.nn.softmax(s, axis=-1) * vis[:, :, None], axis=2)
+
+
+@pytest.mark.parametrize("shape", BLOCK_TABLE_CASES)
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_decode_kernel_block_table_mode(shape, dtype):
     """Explicit block-table mode (the policy step path): fragmented valid,
     compacted table — same output as the oracle, no pad/derive in the
-    wrapper."""
+    wrapper.  The cases walk every shape of a head's walk: no live block,
+    a last pass part full, a full table, unordered tables, the paged pool,
+    weights out, a logit cap, and 1, 2, 8 and 16 blocks a pass."""
     from repro.core.kv_cache import BlockTable
-    b, hq, hkv, p, dh = shape
-    bp = 16
-    ks = jax.random.split(jax.random.PRNGKey(4), 4)
+    opts = shape[5] if len(shape) == 6 else {}
+    b, hq, hkv, p, dh = shape[:5]
+    bp = opts.get("bp", 16)
+    ks = jax.random.split(jax.random.PRNGKey(4), 6)
     q = jax.random.normal(ks[0], (b, 1, hq, dh), dtype)
     k = jax.random.normal(ks[1], (b, hkv, p, dh), dtype)
     v = jax.random.normal(ks[2], (b, hkv, p, dh), dtype)
     valid = jax.random.bernoulli(ks[3], 0.4, (b, hkv, p)).at[:, :, 0].set(True)
-    bt = BlockTable.from_valid(valid, bp)
-    out = dops.dms_decode_attention(q, k, v, valid, block_tbl=bt.tbl,
-                                    block_n=bt.n, block_p=bp)
-    ref = dref.dms_decode_ref(q, k, v, valid)
+    if "n" in opts:
+        tbl, n, listed = _unordered_table(ks[4], b, hkv, p // bp, opts["n"])
+        vis = valid & jnp.repeat(listed, bp, axis=2)
+    else:
+        bt = BlockTable.from_valid(valid, bp)
+        tbl, n, vis = bt.tbl, bt.n, valid
+    extra = _paged_args(ks[5], k, v, bp) if opts.get("paged") else {}
+    cap = opts.get("cap")
+    res = dops.dms_decode_attention(
+        q, k, v, valid, block_tbl=tbl, block_n=n, block_p=bp, logit_cap=cap,
+        need_weights=opts.get("weights", False), **extra)
+    out, weights = res if opts.get("weights") else (res, None)
+    ref = dref.dms_decode_ref(q, k, v, vis, logit_cap=cap)
+    # a head with no live block attends to nothing: the kernel writes zeros
+    ref = jnp.where(jnp.any(vis, axis=-1)[:, None, :, None, None],
+                    ref.reshape(b, 1, hkv, -1, dh), 0).reshape(ref.shape)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
+    if weights is not None:
+        np.testing.assert_allclose(
+            np.asarray(weights), np.asarray(_ref_weights(q, k, vis, cap)),
+            **_tol(dtype))
+
+
+@pytest.mark.parametrize("mode", ["fixed", "paged", "weights"])
+def test_decode_kernel_never_reads_unlisted_blocks(mode):
+    """K/V of every block the table does not list — the entries past ``n``
+    included — is NaN and marked valid: the output stays finite and equal
+    to the oracle over the listed blocks, so the kernel copies exactly the
+    blocks its table lists, whatever its grid."""
+    b, hq, hkv, p, dh, bp = 2, 4, 2, 256, 16, 16
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    q = jax.random.normal(ks[0], (b, 1, hq, dh))
+    k = jax.random.normal(ks[1], (b, hkv, p, dh))
+    v = jax.random.normal(ks[2], (b, hkv, p, dh))
+    tbl, n, listed = _unordered_table(ks[3], b, hkv, p // bp,
+                                      [[5, 13], [16, 1]])
+    dead = ~jnp.repeat(listed, bp, axis=2)[..., None]
+    k_nan, v_nan = jnp.where(dead, jnp.nan, k), jnp.where(dead, jnp.nan, v)
+    valid = jnp.ones((b, hkv, p), bool)
+    vis = valid & ~dead[..., 0]
+    extra = _paged_args(ks[4], k_nan, v_nan, bp) if mode == "paged" else {}
+    res = dops.dms_decode_attention(
+        q, k_nan, v_nan, valid, block_tbl=tbl, block_n=n, block_p=bp,
+        need_weights=mode == "weights", **extra)
+    out, weights = res if mode == "weights" else (res, None)
+    assert bool(jnp.all(jnp.isfinite(out)))
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(dref.dms_decode_ref(q, k, v, vis)),
+        rtol=2e-5, atol=2e-5)
+    if weights is not None:
+        np.testing.assert_allclose(np.asarray(weights),
+                                   np.asarray(_ref_weights(q, k, vis)),
+                                   rtol=2e-5, atol=2e-5)
 
 
 def test_decode_kernel_partial_table_page_sparse():
