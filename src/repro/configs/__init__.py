@@ -26,10 +26,12 @@ _ARCH_MODULES = {
     "qwen-r1-7b": "repro.configs.qwen_r1_7b",
     "qwen-r1-32b": "repro.configs.qwen_r1_32b",
     "llama32-1b": "repro.configs.llama32_1b",
+    # served on the chip as one expert-parallel share (configs/mellum2_12b)
+    "mellum2-12b-a2.5b": "repro.configs.mellum2_12b",
 }
 
 ASSIGNED_ARCHS: List[str] = list(_ARCH_MODULES)[:10]
-PAPER_ARCHS: List[str] = list(_ARCH_MODULES)[10:]
+PAPER_ARCHS: List[str] = list(_ARCH_MODULES)[10:14]
 
 
 def get_arch(name: str) -> ArchConfig:

@@ -96,6 +96,22 @@ RopeKind = Literal["none", "full", "half", "mrope"]
 
 
 @dataclass(frozen=True)
+class YarnConfig:
+    """YaRN RoPE scaling, as transformers' ``_compute_yarn_parameters``
+    computes it: the inverse frequencies ramp from interpolated (divided by
+    ``factor``) to extrapolated between the correction dims that
+    ``beta_fast`` / ``beta_slow`` rotations over
+    ``original_max_position_embeddings`` give, and cos and sin are scaled
+    by ``attention_factor``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclass(frozen=True)
 class AttentionConfig:
     num_heads: int
     num_kv_heads: int
@@ -107,6 +123,9 @@ class AttentionConfig:
     logit_softcap: Optional[float] = None     # gemma2 attn softcap
     causal: bool = True                       # False for encoder self-attention
     qk_norm: bool = False
+    # YaRN on the full ("attn") layers; windowed ("attn_local") layers keep
+    # the plain RoPE of ``rope_theta`` (Mellum2's per-layer-kind RoPE)
+    yarn: Optional[YarnConfig] = None
 
     @property
     def q_per_kv(self) -> int:
@@ -119,6 +138,15 @@ class MoEConfig:
     top_k: int
     router_jitter: float = 0.0
     aux_loss_weight: float = 0.01
+    # expert parallelism: the ids of the experts this chip holds (weights
+    # for these alone); the router still scores all ``num_experts`` and the
+    # layer returns the held experts' part of the result.  None = all.
+    held_experts: Optional[Tuple[int, ...]] = None
+
+    @property
+    def num_held(self) -> int:
+        return (self.num_experts if self.held_experts is None
+                else len(self.held_experts))
 
 
 @dataclass(frozen=True)
@@ -329,7 +357,7 @@ class ArchConfig:
         if self.mlp.moe is None:
             return per_expert
         moe = self.mlp.moe
-        n_experts = moe.top_k if active_only else moe.num_experts
+        n_experts = moe.top_k if active_only else moe.num_held
         return n_experts * per_expert + d * moe.num_experts  # + router
 
 

@@ -540,12 +540,17 @@ class SlotDMSCache(LaneSliceable, HasBlockTable):
     dms_active: bool = dataclasses.field(metadata={"static": True}, default=True)
     pool: Optional[block_pool.BlockPool] = None
     phys: Optional[jnp.ndarray] = None       # (B, H, NB) int32, -1 = unmapped
+    #: sliding-window layer's window W (0 = full attention): before token t
+    #: is stored, the slot holding position t - W is freed, so the arena
+    #: holds exactly what the window lets query t see (0 <= t - j < W)
+    attn_window: int = dataclasses.field(metadata={"static": True},
+                                         default=0)
 
     @staticmethod
     def init(batch: int, kv_heads: int, num_slots: int, head_dim: int,
              window: int, dtype=jnp.bfloat16, dms_active: bool = True,
              block_p: int = 0, paged: bool = False,
-             pool_blocks: Optional[int] = None):
+             pool_blocks: Optional[int] = None, attn_window: int = 0):
         p = _round_up(num_slots, block_p)
         pool = phys = None
         if paged:
@@ -574,6 +579,7 @@ class SlotDMSCache(LaneSliceable, HasBlockTable):
             dms_active=dms_active,
             pool=pool,
             phys=phys,
+            attn_window=attn_window,
         )
 
     @staticmethod
@@ -596,7 +602,12 @@ class SlotDMSCache(LaneSliceable, HasBlockTable):
         slot_c = jnp.clip(slot, 0, self.valid.shape[2] - 1)
         was_valid = jnp.take_along_axis(self.valid, slot_c[..., None], axis=2)[..., 0]
         do_evict = do_evict & was_valid
+        return self._free(slot_c, do_evict, active)
 
+    def _free(self, slot_c, do_evict, active=None) -> "SlotDMSCache":
+        """Free slot ``slot_c`` (B, H) where ``do_evict``: it turns invalid,
+        joins the free ring's tail and leaves the block table."""
+        b, h = self.valid.shape[:2]
         p_idx = jnp.arange(self.valid.shape[2])
         hit = (p_idx[None, None] == slot_c[..., None]) & do_evict[..., None]
         valid = self.valid & ~hit
@@ -615,6 +626,15 @@ class SlotDMSCache(LaneSliceable, HasBlockTable):
         return dataclasses.replace(
             self, valid=valid, pos=pos, free_ring=free_ring,
             free_count=free_count, blocks=blocks, pool=pool, phys=phys)
+
+    def _expire_window(self, active=None) -> "SlotDMSCache":
+        """Sliding-window layers: free the slot of position t - W, the one
+        position that leaves the window as token t arrives (positions are
+        consecutive per lane, so it is the only one)."""
+        j = self.length - self.attn_window                          # (B,)
+        at_j = self.valid & (self.pos == j[:, None, None])          # (B,H,P)
+        slot = jnp.argmax(at_j, axis=2).astype(jnp.int32)
+        return self._free(slot, jnp.any(at_j, axis=2), active)
 
     def _allocate(self) -> Tuple["SlotDMSCache", jnp.ndarray]:
         """Pop a slot per (B, H).  Returns (cache, slot (B,H))."""
@@ -640,6 +660,8 @@ class SlotDMSCache(LaneSliceable, HasBlockTable):
         k_new/v_new: (B, H, 1, Dh) post-RoPE; alpha_new: (B, H) bool.
         """
         cache = self._execute_pending(active)
+        if cache.attn_window:
+            cache = cache._expire_window(active)
         cache, slot = cache._allocate()
         t = cache.length                                                  # (B,)
         p_idx = jnp.arange(cache.valid.shape[2])
@@ -748,7 +770,8 @@ class SlotDMSCache(LaneSliceable, HasBlockTable):
             bi = jnp.arange(b)[:, None, None]
             hi = jnp.arange(h)[None, :, None]
             ps = ps.at[bi, hi, idx[None, None, :]].set(
-                jnp.where(in_window[None, None, :], rank, -1).astype(jnp.int32))
+                jnp.where(in_window[None, None, :] & retained, rank,
+                          -1).astype(jnp.int32))
             pa = pa.at[bi, hi, idx[None, None, :]].set(
                 jnp.where(in_window[None, None, :], alpha_bin, False))
             cache = dataclasses.replace(cache, pending_slot=ps[..., :w], pending_alpha=pa[..., :w])

@@ -617,7 +617,12 @@ class WindowPolicy(_SlotRingMixin, KVPolicy):
 
 @register_policy("dms")
 class DMSPolicy(_SlotRingMixin, KVPolicy):
-    """The paper's policy: slot-compacted arena, delayed eviction (§3.3)."""
+    """The paper's policy: slot-compacted arena, delayed eviction (§3.3).
+
+    In a sliding-window layer the arena is provisioned for the window, and
+    the cache frees each position as it leaves the window (``attn_window``):
+    a token leaves at its DMS eviction or at the window's edge, whichever
+    comes first, and its slot returns to the free ring."""
 
     alpha_mode = "dms"
 
@@ -629,7 +634,8 @@ class DMSPolicy(_SlotRingMixin, KVPolicy):
         return SlotDMSCache.init(batch, a.num_kv_heads, min(slots, eff_len + 1),
                                  a.head_dim, arch.dms.window, dtype,
                                  block_p=cfg.block_p, paged=cfg.paged,
-                                 pool_blocks=cfg.pool_blocks)
+                                 pool_blocks=cfg.pool_blocks,
+                                 attn_window=layer_window or 0)
 
     def decode_update(self, cache, q, k_new, v_new, aux):
         return self._slot_update(cache, k_new, v_new, aux)
@@ -639,10 +645,16 @@ class DMSPolicy(_SlotRingMixin, KVPolicy):
         eff_len = (min(max_len, layer_window + 1) if layer_window is not None
                    else max_len)
         slots = SlotDMSCache.provision_slots(eff_len, cfg.cr, arch.dms.window)
+        if layer_window is not None:
+            # what decode's window rule has freed by now: every position
+            # at or below t - W, t = k.shape[2] the next position stored
+            retained = retained & (positions >= k.shape[2] - layer_window)
         cache = SlotDMSCache.from_prefill(
             k, v, positions, retained, arch.dms.window,
             min(slots, eff_len + 1), alpha_bin=alpha_bin,
             block_p=cfg.block_p)
+        if layer_window is not None:
+            cache = dataclasses.replace(cache, attn_window=layer_window)
         if cfg.paged:
             cache = pack_dense(cache, cfg.pool_blocks)
         return cache

@@ -291,8 +291,10 @@ def full_attention(
         rope_pos = positions
         if cfg.rope == "mrope" and positions.ndim == 1:
             rope_pos = jnp.broadcast_to(positions, (3,) + positions.shape)
-        q = apply_rope(q_raw, rope_pos, cfg.rope_theta, cfg.rope, cfg.mrope_sections)
-        k = apply_rope(k, rope_pos, cfg.rope_theta, cfg.rope, cfg.mrope_sections) \
+        q = apply_rope(q_raw, rope_pos, cfg.rope_theta, cfg.rope,
+                       cfg.mrope_sections, cfg.yarn)
+        k = apply_rope(k, rope_pos, cfg.rope_theta, cfg.rope,
+                       cfg.mrope_sections, cfg.yarn) \
             if kv_override is None else k
     else:
         q = q_raw
@@ -403,8 +405,10 @@ def decode_attention(
     if cfg.rope != "none":
         rpos = (jnp.broadcast_to(pos_arr[None], (3, b, 1))
                 if cfg.rope == "mrope" else pos_arr)
-        q = apply_rope(q_raw, rpos, cfg.rope_theta, cfg.rope, cfg.mrope_sections)
-        k_new = apply_rope(k_new, rpos, cfg.rope_theta, cfg.rope, cfg.mrope_sections)
+        q = apply_rope(q_raw, rpos, cfg.rope_theta, cfg.rope,
+                       cfg.mrope_sections, cfg.yarn)
+        k_new = apply_rope(k_new, rpos, cfg.rope_theta, cfg.rope,
+                           cfg.mrope_sections, cfg.yarn)
     else:
         q = q_raw
 

@@ -6,12 +6,14 @@ softmax statistics in fp32.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.config import ArchConfig, MLPConfig
+from repro.core.config import ArchConfig, MLPConfig, YarnConfig
 
 
 def _dtype(cfg: ArchConfig):
@@ -62,16 +64,44 @@ def rope_freqs(head_dim: int, theta: float, rotary_dim: Optional[int] = None) ->
     return 1.0 / (theta ** (jnp.arange(0, rd, 2, dtype=jnp.float32) / rd))
 
 
+def yarn_freqs(head_dim: int, theta: float, yarn: YarnConfig) -> np.ndarray:
+    """YaRN inverse frequencies (Dh/2,), float32, as transformers'
+    ``_compute_yarn_parameters`` (``truncate`` true): the correction range
+    from ``beta_fast`` / ``beta_slow`` rotations, floored and ceiled, and a
+    linear ramp from extrapolated (below ``low``) to interpolated (÷
+    ``factor``, above ``high``) frequencies.  Static: computed on the host
+    once per trace."""
+    def corr_dim(rot):
+        return (head_dim * math.log(yarn.original_max_position_embeddings
+                                    / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    pos_freqs = theta ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                          / head_dim)
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    extra = 1.0 - ramp                  # share of the extrapolated frequency
+    inv = (1.0 / (yarn.factor * pos_freqs)) * (1 - extra) \
+        + (1.0 / pos_freqs) * extra
+    return inv.astype(np.float32)
+
+
 def apply_rope(
     x: jnp.ndarray,
     positions: jnp.ndarray,
     theta: float,
     kind: str = "full",
     mrope_sections: Tuple[int, ...] = (),
+    yarn: Optional[YarnConfig] = None,
 ) -> jnp.ndarray:
     """x: (..., T, H, Dh); positions: (..., T) int — or (3, ..., T) for mrope.
 
-    * full: rotate all head dims.
+    * full: rotate all head dims (with ``yarn``: YaRN frequencies, and cos
+      and sin scaled by its ``attention_factor``).
     * half: rotate the first Dh/2 dims only (ChatGLM-style 2-d RoPE).
     * mrope: Qwen2-VL multimodal RoPE — the Dh/2 frequency slots are split
       into sections (temporal, height, width), each driven by its own
@@ -96,6 +126,10 @@ def apply_rope(
         pos_per_freq = pos[sec_id]                          # (Dh/2, ..., T)
         ang = jnp.moveaxis(pos_per_freq, 0, -1) * freqs     # (..., T, Dh/2)
         return _apply_angles(x, ang)
+    if yarn is not None:
+        ang = positions.astype(jnp.float32)[..., None] * yarn_freqs(
+            dh, theta, yarn)
+        return _apply_angles(x, ang, yarn.attention_factor)
     return _rotate(x, positions.astype(jnp.float32), theta)
 
 
@@ -106,9 +140,12 @@ def _rotate(x: jnp.ndarray, pos: jnp.ndarray, theta: float) -> jnp.ndarray:
     return _apply_angles(x, ang)
 
 
-def _apply_angles(x: jnp.ndarray, ang: jnp.ndarray) -> jnp.ndarray:
+def _apply_angles(x: jnp.ndarray, ang: jnp.ndarray,
+                  scale: float = 1.0) -> jnp.ndarray:
     """ang: (..., T, Dh_rot/2); x: (..., T, H, Dh_rot)."""
     sin, cos = jnp.sin(ang), jnp.cos(ang)
+    if scale != 1.0:
+        sin, cos = sin * scale, cos * scale
     sin, cos = sin[..., None, :], cos[..., None, :]          # broadcast over heads
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., ::2], xf[..., 1::2]
@@ -133,10 +170,11 @@ def init_mlp(key, d_model: int, cfg: MLPConfig) -> dict:
                 "w_down": dense_init(ks[2], f, d_model),
             }
         return {"w_up": dense_init(ks[0], d_model, f), "w_down": dense_init(ks[1], f, d_model)}
-    e = cfg.moe.num_experts
+    e = cfg.moe.num_held            # expert weights: the held ones alone
     def einit(k, a, b):
         return jax.random.normal(k, (e, a, b), jnp.float32) * (a ** -0.5)
-    p = {"router": dense_init(ks[3], d_model, e, scale=0.02)}
+    p = {"router": dense_init(ks[3], d_model, cfg.moe.num_experts,
+                              scale=0.02)}
     if cfg.kind in ("swiglu", "geglu"):
         p.update(
             w_gate=einit(ks[0], d_model, f),
@@ -180,8 +218,18 @@ def _maybe_shard(x: jnp.ndarray, *spec) -> jnp.ndarray:
         return x
 
 
-def mlp_apply(p: dict, x: jnp.ndarray, cfg: MLPConfig, dtype) -> Tuple[jnp.ndarray, dict]:
-    """Returns (y, aux) — aux carries the MoE load-balancing loss."""
+def mlp_apply(p: dict, x: jnp.ndarray, cfg: MLPConfig, dtype,
+              use_kernel: bool = False,
+              layer: Optional[jnp.ndarray] = None
+              ) -> Tuple[jnp.ndarray, dict]:
+    """Returns (y, aux) — aux carries the MoE load-balancing loss, and for
+    the expert layer of :func:`moe_share_apply` the pairs it computed.
+
+    An MoE layer runs :func:`moe_share_apply` at decode (one token a row)
+    and wherever the chip holds a share of the experts; full sequences of a
+    layer that holds them all keep :func:`moe_apply`.  ``layer``: the
+    expert matrices in ``p`` are stacked over layers, and this is the one
+    to apply (:func:`moe_share_apply`)."""
     if cfg.moe is None:
         xd = x.astype(dtype)
         if cfg.kind in ("swiglu", "geglu"):
@@ -189,7 +237,62 @@ def mlp_apply(p: dict, x: jnp.ndarray, cfg: MLPConfig, dtype) -> Tuple[jnp.ndarr
         else:
             h = _act(xd @ p["w_up"].astype(dtype), cfg.kind)
         return (h @ p["w_down"].astype(dtype)).astype(x.dtype), {}
+    if cfg.moe.held_experts is not None or x.shape[1] == 1:
+        return moe_share_apply(p, x, cfg, dtype, use_kernel=use_kernel,
+                               layer=layer)
     return moe_apply(p, x, cfg, dtype)
+
+
+EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
+
+
+def moe_share_apply(p: dict, x: jnp.ndarray, cfg: MLPConfig, dtype,
+                    use_kernel: bool = False,
+                    layer: Optional[jnp.ndarray] = None
+                    ) -> Tuple[jnp.ndarray, dict]:
+    """The expert layer as one chip of an expert-parallel deployment sees
+    it: route each token over all ``num_experts``, compute only the pairs
+    routed to the experts this chip holds (``moe.held_experts``; all when
+    None), and return their part of the result,
+    ``y = sum over the token's top-k picks e that are held of p_e FFN_e(x)``
+    (the other chips' parts would arrive through the all-to-all, which one
+    chip does without).  The pairs run through the ``moe_experts`` kernel
+    (``use_kernel``) or its jnp oracle — no per-row capacity, no dispatch
+    buffer, no dropped token.  aux ``moe_pairs`` (B,) counts each row's
+    pairs computed here.  With ``layer``, the expert matrices in ``p``
+    carry a leading axis of stacked layers, and the kernel reads layer
+    ``layer``'s blocks in place (a slice handed to it would be copied
+    whole first); stored in another dtype, the layer is sliced and cast."""
+    from repro.kernels.moe_experts import ops as moe_ops
+    moe = cfg.moe
+    if cfg.kind != "swiglu":
+        raise NotImplementedError(f"expert layer: {cfg.kind} experts")
+    b, t, d = x.shape
+    held = (tuple(range(moe.num_experts)) if moe.held_experts is None
+            else moe.held_experts)
+    xt = x.reshape(b * t, d).astype(dtype)
+    with jax.named_scope("moe_router"):
+        # softmax over all the router's logits, top-k, renormalised
+        logits = jnp.dot(xt, p["router"].astype(dtype),
+                         preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_e = jax.lax.top_k(probs, moe.top_k)
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        groups = moe_ops.group_pairs(top_e, top_p, held, moe.num_experts)
+    ws = tuple(p[n] for n in EXPERT_MATRICES)
+    if layer is not None and any(w.dtype != dtype for w in ws):
+        ws, layer = tuple(w[layer] for w in ws), None
+    with jax.named_scope("moe_experts"):
+        y = moe_ops.moe_experts(
+            xt, *(w.astype(dtype) for w in ws), *groups, layer=layer,
+            use_kernel=use_kernel)
+    is_held = jnp.isin(top_e, jnp.asarray(held, jnp.int32))
+    frac_tokens = jnp.mean(jnp.sum(jax.nn.one_hot(top_e, moe.num_experts),
+                                   axis=1), axis=0)
+    aux = {"moe_aux_loss": moe.aux_loss_weight * moe.num_experts
+           * jnp.sum(frac_tokens * jnp.mean(probs, axis=0)),
+           "moe_pairs": jnp.sum(is_held.reshape(b, t * moe.top_k), axis=1)}
+    return y.reshape(b, t, d).astype(x.dtype), aux
 
 
 def moe_apply(p: dict, x: jnp.ndarray, cfg: MLPConfig, dtype,

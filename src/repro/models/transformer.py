@@ -27,7 +27,8 @@ from repro.core.config import ArchConfig, KVPolicyConfig
 from repro.models import attention as attn_lib
 from repro.models import rglru as rglru_lib
 from repro.models import ssd as ssd_lib
-from repro.models.layers import init_mlp, init_norm, mlp_apply, norm_apply, softcap
+from repro.models.layers import (EXPERT_MATRICES, init_mlp, init_norm, mlp_apply,
+                                 norm_apply, softcap)
 
 
 # ---------------------------------------------------------------------------
@@ -126,6 +127,14 @@ def _layer_window(arch: ArchConfig, kind: str) -> Optional[int]:
     return None
 
 
+def _layer_attn(arch: ArchConfig, kind: str):
+    """The attention config a layer of ``kind`` runs: YaRN applies to full
+    layers only, windowed layers keep the plain RoPE."""
+    if kind == "attn_local" and arch.attn.yarn is not None:
+        return dataclasses.replace(arch.attn, yarn=None)
+    return arch.attn
+
+
 def _apply_block_full(
     p: dict, x: jnp.ndarray, arch: ArchConfig, kind: str, *,
     mode: str, rng, positions, neuron_scale, use_kernel, collect_kv,
@@ -133,7 +142,9 @@ def _apply_block_full(
 ) -> Tuple[jnp.ndarray, Dict[str, Any]]:
     aux: Dict[str, Any] = {}
     if kind in ("attn", "attn_local"):
-        acfg = arch.attn if causal else dataclasses.replace(arch.attn, causal=False)
+        acfg = _layer_attn(arch, kind)
+        if not causal:
+            acfg = dataclasses.replace(acfg, causal=False)
         h = norm_apply(p["attn_norm"], x, arch.norm, arch.norm_eps)
         a_out, a_aux = attn_lib.full_attention(
             p["attn"], h, acfg, arch,
@@ -545,17 +556,26 @@ def decode_step(
     x = (embed_override if embed_override is not None
          else embed_tokens(params, token, arch))
     impls = set()   # trace-time: attention implementations actually traced
+    blocks, experts = _split_experts(params["blocks"], arch)
+
+    def mlp(p, pi, h, li):
+        mp, layer = p["mlp"], None
+        if str(pi) in experts:
+            mp, layer = dict(mp, **experts[str(pi)]), li
+        with jax.named_scope("mlp"):
+            return mlp_apply(mp, h, arch.mlp, jnp.dtype(arch.dtype),
+                             use_kernel=use_kernel, layer=layer)
 
     def body(carry, xs):
-        x_t, live, reads = carry
-        blk, cache = xs
+        x_t, live, reads, pairs = carry
+        blk, cache, li = xs if experts else xs + (None,)
         new_caches = {}
         for pi, kind in enumerate(arch.layer_pattern):
             p = blk[str(pi)]
             if kind in ("attn", "attn_local"):
                 h = norm_apply(p["attn_norm"], x_t, arch.norm, arch.norm_eps)
                 a_out, new_c, aux = attn_lib.decode_attention(
-                    p["attn"], h, cache[str(pi)], arch.attn, arch,
+                    p["attn"], h, cache[str(pi)], _layer_attn(arch, kind), arch,
                     layer_window=_layer_window(arch, kind), pos_t=pos_t,
                     use_kernel=use_kernel, active=active)
                 impls.add(aux["attn_impl"])
@@ -584,9 +604,8 @@ def decode_step(
                     x_t = x_t + c_out
                 if arch.mlp is not None:
                     h = norm_apply(p["mlp_norm"], x_t, arch.norm, arch.norm_eps)
-                    with jax.named_scope("mlp"):
-                        m_out, _ = mlp_apply(p["mlp"], h, arch.mlp,
-                                             jnp.dtype(arch.dtype))
+                    m_out, m_aux = mlp(p, pi, h, li)
+                    pairs = pairs + m_aux.get("moe_pairs", 0)
                     if arch.post_norm:
                         m_out = norm_apply(p["mlp_post_norm"], m_out, arch.norm, arch.norm_eps)
                     x_t = x_t + m_out
@@ -600,28 +619,31 @@ def decode_step(
                 x_t = x_t + r_out
                 if arch.mlp is not None:
                     h = norm_apply(p["mlp_norm"], x_t, arch.norm, arch.norm_eps)
-                    with jax.named_scope("mlp"):
-                        m_out, _ = mlp_apply(p["mlp"], h, arch.mlp,
-                                             jnp.dtype(arch.dtype))
+                    m_out, m_aux = mlp(p, pi, h, li)
+                    pairs = pairs + m_aux.get("moe_pairs", 0)
                     x_t = x_t + m_out
             new_caches[str(pi)] = new_c
-        return (x_t, live, reads), new_caches
+        return (x_t, live, reads, pairs), new_caches
 
     b = x.shape[0]
     zero = jnp.zeros((b,), jnp.float32)
+    carry0 = (x, zero, zero, jnp.zeros((b,), jnp.int32))
+    nsb = arch.num_superblocks
+    # each superblock's index, for the expert layers to read their weights
+    idx = (jnp.arange(nsb),) if experts else ()
     if scan_layers:
-        (x, live, reads), new_state = jax.lax.scan(
-            body, (x, zero, zero), (params["blocks"], state))
+        (x, live, reads, pairs), new_state = jax.lax.scan(
+            body, carry0, (blocks, state) + idx)
     else:
-        carry = (x, zero, zero)
+        carry = carry0
         outs = []
-        nsb = arch.num_superblocks
         for s in range(nsb):
-            blk_s = jax.tree_util.tree_map(lambda a: a[s], params["blocks"])
+            blk_s = jax.tree_util.tree_map(lambda a: a[s], blocks)
             st_s = jax.tree_util.tree_map(lambda a: a[s], state)
-            carry, y = body(carry, (blk_s, st_s))
+            carry, y = body(carry, (blk_s, st_s, s) if experts
+                            else (blk_s, st_s))
             outs.append(y)
-        (x, live, reads) = carry
+        (x, live, reads, pairs) = carry
         new_state = jax.tree_util.tree_map(lambda *zs: jnp.stack(zs), *outs)
     if active is not None:
         with jax.named_scope("lane_select"):
@@ -633,8 +655,29 @@ def decode_step(
     # traced the Pallas kernel — a requested kernel that silently fell back
     # to the reference einsum is visible in the step metrics
     kernel_only = 1 if (impls and impls == {"kernel"}) else 0
+    # moe_pairs (B,): (token, expert) pairs the expert layers computed for
+    # each lane, summed over layers (zeros for a dense model)
     return logits, new_state, {"live_tokens": live, "reads_tokens": reads,
-                               "attn_impl_kernel": kernel_only}
+                               "attn_impl_kernel": kernel_only,
+                               "moe_pairs": pairs}
+
+
+def _split_experts(blocks: dict, arch: ArchConfig) -> Tuple[dict, dict]:
+    """(blocks without the expert matrices of the MoE layers, those
+    matrices by pattern position, still stacked over superblocks): the
+    decode scan hands each layer its index, and the expert kernel reads
+    that layer's blocks from the stack, where a per-layer slice would be
+    copied whole before the call."""
+    if arch.mlp is None or arch.mlp.moe is None:
+        return blocks, {}
+    rest, experts = dict(blocks), {}
+    for pi, blk in blocks.items():
+        mlp = blk.get("mlp", {})
+        if all(n in mlp for n in EXPERT_MATRICES):
+            experts[pi] = {n: mlp[n] for n in EXPERT_MATRICES}
+            rest[pi] = dict(blk, mlp={n: v for n, v in mlp.items()
+                                      if n not in EXPERT_MATRICES})
+    return rest, experts
 
 
 def lane_select(mask: jnp.ndarray, on_true: Any, on_false: Any) -> Any:

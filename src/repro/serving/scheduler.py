@@ -241,9 +241,11 @@ class TickRecord:
     ``returned`` (the call returned: the work is enqueued, the readback
     begins), ``ready`` (the host arrays are in hand, ``sched.book``
     begins) and ``end``.  ``active_lanes`` counts lanes the chunk stepped,
-    ``prefill_lanes`` lanes it fed prompt tokens, and ``tokens`` the output
+    ``prefill_lanes`` lanes it fed prompt tokens, ``tokens`` the output
     tokens added to chains since the last logged row (first tokens sampled
-    at a fork included), so the log's tokens add up to the results'."""
+    at a fork included), so the log's tokens add up to the results', and
+    ``moe_pairs`` the (token, expert) pairs the chunk's expert layers
+    computed, over its steps, lanes and layers (0 for a dense model)."""
 
     tick: int
     start: float
@@ -255,6 +257,7 @@ class TickRecord:
     active_lanes: int = 0
     prefill_lanes: int = 0
     tokens: int = 0
+    moe_pairs: int = 0
 
 
 class _ReqState:
@@ -373,7 +376,8 @@ def make_chunk_fn(arch, *, use_kernel: bool = False,
             last_logits = jnp.where(active[:, None], logits, last_logits)
             return ((state, cur_tok, pos, finished, emit_cnt, rng,
                      last_logits, bad),
-                    (emitted, aux["live_tokens"], aux["reads_tokens"], active))
+                    (emitted, aux["live_tokens"], aux["reads_tokens"], active,
+                     aux["moe_pairs"]))
 
         b = feed.shape[0]
         carry0 = (state, cur_tok, pos, finished, jnp.zeros((b,), jnp.int32),
@@ -382,9 +386,10 @@ def make_chunk_fn(arch, *, use_kernel: bool = False,
         carry, ys = jax.lax.scan(body, carry0, (feed.T, feed_valid.T))
         (state, cur_tok, pos, finished, emit_cnt, rng, last_logits,
          bad) = carry
-        emitted, live, reads, act = ys                 # each (C, B)
+        emitted, live, reads, act, pairs = ys          # each (C, B)
+        # ``pairs`` last, so the outputs before it keep their indices
         return (state, cur_tok, pos, finished, emit_cnt, rng, last_logits,
-                emitted, live, reads, act, bad)
+                emitted, live, reads, act, bad, pairs)
 
     return chunk_fn
 
@@ -1254,7 +1259,7 @@ class Scheduler:
             del args
         with self._phase("sched.readback", "returned"):
             (self.state, cur_tok, pos, finished, _, self.rng, last_logits,
-             emitted, live, reads, act, bad) = out
+             emitted, live, reads, act, bad, pairs) = out
             # the scheduler's ONE sanctioned host sync: once per chunk, never
             # per step (the host-sync tripwire in repro.analysis enforces
             # this).  The failure tripwires ride the same boundary: the
@@ -1269,6 +1274,7 @@ class Scheduler:
                 reads = np.asarray(reads)
                 act = np.asarray(act)
                 bad = np.asarray(bad)              # (B,) non-finite logits
+                self._row.moe_pairs = int(np.asarray(pairs).sum())
                 exhausted = (self._pools_exhausted()
                              if self._pool_descs
                              and self.on_pressure != "ignore" else False)
