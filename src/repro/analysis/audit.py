@@ -81,14 +81,15 @@ def policy_cfg(policy: str, paged: bool) -> KVPolicyConfig:
                           quest_top_pages=2)
 
 
-def _arena_elems(state) -> int:
-    """Smallest fully-provisioned KV arena in the state: any op at this many
-    elements (or more) touches a whole arena."""
+def _arena_elems(state, *, layer: bool = False) -> int:
+    """Smallest fully-provisioned KV arena in the state (stacked over
+    superblocks; with ``layer``, one layer's): any op at this many elements
+    (or more) touches a whole arena."""
     sizes = []
     for pc in policy_lib.iter_policy_caches(state):
         pool = getattr(pc.cache, "pool", None)
         arr = pool.k if pool is not None else pc.cache.k
-        sizes.append(int(np.prod(arr.shape)))
+        sizes.append(int(np.prod(arr.shape[1:] if layer else arr.shape)))
     return min(sizes)
 
 
@@ -111,7 +112,8 @@ def audit_combo(arch, params, policy: str, paged: bool,
     def lint(kind: str, fn, *args, table_mode: bool = False):
         jaxpr = dce(trace_jaxpr(fn, *args))
         ctx = LintContext(arena_elems=elems, table_mode=table_mode,
-                          allow=ALLOW.get(kind, ()))
+                          allow=ALLOW.get(kind, ()),
+                          layer_elems=_arena_elems(state, layer=True))
         findings.extend(run_passes(jaxpr, ctx, path=f"{tag}/{kind}"))
 
     lint("decode",

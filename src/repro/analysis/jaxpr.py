@@ -13,19 +13,22 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def walk_eqns(jaxpr) -> Iterator[Any]:
+def walk_eqns(jaxpr, skip=()) -> Iterator[Any]:
     """Yield every eqn in ``jaxpr``, recursing through the sub-jaxprs hiding
-    in eqn params (``scan``/``cond``/``while``/``pjit``/``custom_vjp``/...)."""
+    in eqn params (``scan``/``cond``/``while``/``pjit``/``custom_vjp``/...),
+    except those of the primitives named in ``skip``."""
     from jax.extend.core import ClosedJaxpr, Jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
+        if eqn.primitive.name in skip:
+            continue
         for val in eqn.params.values():
             for sub in jax.tree_util.tree_leaves(
                     val, is_leaf=lambda x: isinstance(x, (Jaxpr, ClosedJaxpr))):
                 if isinstance(sub, ClosedJaxpr):
-                    yield from walk_eqns(sub.jaxpr)
+                    yield from walk_eqns(sub.jaxpr, skip)
                 elif isinstance(sub, Jaxpr):
-                    yield from walk_eqns(sub)
+                    yield from walk_eqns(sub, skip)
 
 
 def trace_jaxpr(fn: Callable, *args, **kwargs):

@@ -9,8 +9,8 @@ inside ``scan``/``cond``/``pjit`` bodies are linted like top-level ops.
 Severity policy (docs/analysis.md):
 
 * ``error`` — a known-pathological traffic pattern on the decode/fork/reclaim
-  path (full-arena copy, arena-sized recast, KV upcast, whole-arena gather in
-  table mode).  Always gates the audit.
+  path (full-arena copy, arena-sized recast, KV upcast, whole-arena gather or
+  select in table mode).  Always gates the audit.
 * ``warn``  — suspicious but occasionally legitimate (a scalar float
   returned from a traced step).  Gates the audit unless allowlisted.
 * ``info``  — an allowlisted finding, kept visible in reports, never gates.
@@ -59,6 +59,10 @@ class LintContext:
     arena_elems: int
     table_mode: bool = False
     allow: Tuple[str, ...] = ()        # rule names allowlisted for this entry
+    #: elements of ONE layer's K/V arena (``arena_elems`` counts a whole
+    #: superblock-stacked arena); 0 = ``arena_elems``.  ``arena-select``
+    #: measures against it: a step may touch rows of a layer, never a layer
+    layer_elems: int = 0
 
 
 _PASSES: Dict[str, Callable] = {}
@@ -170,10 +174,12 @@ def _kv_upcast(jaxpr, ctx):
 @register_pass("arena-gather")
 def _arena_gather(jaxpr, ctx):
     """In table mode, ``gather``/``dynamic_slice`` whose *operand* is the
-    whole provisioned arena: the kernel consumes the arena in place through
-    the scalar-prefetched block table, so a step-path gather over it means
-    the wrapper is re-materializing table order (the dead-block-DMA pitfall
-    reintroduced one level up).
+    whole provisioned arena and whose output is a layer's arena or more:
+    the kernel consumes the arena in place through the scalar-prefetched
+    block table, so a step-path gather over it means the wrapper is
+    re-materializing table order (the dead-block-DMA pitfall reintroduced
+    one level up), or slicing a layer out of the stack for the kernel.  A
+    read of a few rows (DMC's merge target, one row a lane-head) is not.
 
     Rank-<3 operands are exempt: KV arenas and page pools are always ≥3-D
     ((B,H,S,Dh) / (NPOOL,bp,Dh)), while per-token lookups into big 2-D
@@ -186,10 +192,52 @@ def _arena_gather(jaxpr, ctx):
         op = eqn.invars[0].aval
         if hasattr(op, "shape") and len(op.shape) >= 3 \
                 and int(np.prod(op.shape)) >= ctx.arena_elems \
+                and out_elems(eqn) >= (ctx.layer_elems or ctx.arena_elems) \
                 and jnp.issubdtype(op.dtype, jnp.floating):
             yield Finding("error", "arena-gather",
                           "gather/slice over the whole provisioned arena "
                           "in table mode",
+                          eqn=_eqn_str(eqn), nbytes=_out_nbytes(eqn))
+
+
+@register_pass("arena-select")
+def _arena_select(jaxpr, ctx):
+    """In table (kernel) mode, a decode step touches a fixed K/V arena only
+    by lane-masked row writes, which XLA performs in place, and by the
+    kernel's reads (docs/policies.md).  Two shapes of whole-arena traffic
+    are flagged, each at one layer's arena or more:
+
+    * an elementwise select (``select_n``) producing a float arena — a
+      token write as ``where(hit, k_new, arena)``, or a lane freeze that
+      selects the K/V arenas against their old values, each reads and
+      rewrites every slot;
+    * a ``dynamic_update_slice`` or scatter whose *update* is a whole
+      layer's arena — writing a layer back into a stack (a scan's stacked
+      output, ``stack.at[l].set(layer)``) copies it.
+
+    Row scatters (updates of a few rows) pass, and so does the kernel's own
+    body, whose selects act on VMEM tiles.  The reference attention path
+    materializes arenas by design (the layer's slice, a paged dense view),
+    so the pass runs in table mode only."""
+    if not ctx.table_mode:
+        return
+    elems = ctx.layer_elems or ctx.arena_elems
+    for eqn in walk_eqns(jaxpr, skip=("pallas_call",)):
+        name = eqn.primitive.name
+        if name == "select_n":
+            big = out_elems(eqn) >= elems
+            dtype = eqn.outvars[0].aval.dtype
+            what = "whole-arena select"
+        elif name == "dynamic_update_slice" or name.startswith("scatter"):
+            upd = eqn.invars[1 if name == "dynamic_update_slice" else 2].aval
+            big = int(np.prod(upd.shape)) >= elems
+            dtype = upd.dtype
+            what = "whole-layer arena written into a stack"
+        else:
+            continue
+        if big and jnp.issubdtype(dtype, jnp.floating):
+            yield Finding("error", "arena-select",
+                          f"{what} on a decode step (write rows in place)",
                           eqn=_eqn_str(eqn), nbytes=_out_nbytes(eqn))
 
 

@@ -29,9 +29,10 @@ import jax.numpy as jnp
 
 from repro.core import block_pool
 from repro.core.kv_cache import (BlockTable, HasBlockTable,
-                                 LaneSliceable, _round_up,
+                                 LaneSliceable, _append_slots, _round_up,
                                  _tree_dataclass, event_mask, init_paged,
-                                 prefix_block_spec, INVALID_POS)
+                                 prefix_block_spec, read_rows, store_token,
+                                 write_rows, INVALID_POS)
 
 NEG_INF = -1e30
 
@@ -83,15 +84,7 @@ class TOVACache(LaneSliceable, HasBlockTable):
         slot = jnp.argmax(free, axis=2).astype(jnp.int32)         # first free
         hit = (jnp.arange(p)[None, None] == slot[..., None])
         newly = jnp.take_along_axis(free, slot[..., None], axis=2)[..., 0]
-        pool, phys = self.pool, self.phys
-        if pool is not None:
-            pool, phys = block_pool.token_write(
-                pool, phys, slot[..., None], k_new, v_new,
-                event_mask(active, slot.shape)[..., None])
-            k, v = self.k, self.v       # zero-width; bytes go to the pool
-        else:
-            k = jnp.where(hit[..., None], k_new.astype(self.k.dtype), self.k)
-            v = jnp.where(hit[..., None], v_new.astype(self.v.dtype), self.v)
+        k, v, pool, phys = store_token(self, slot, k_new, v_new, active)
         return dataclasses.replace(
             self,
             k=k, v=v,
@@ -182,15 +175,7 @@ class H2OCache(LaneSliceable, HasBlockTable):
         slot = jnp.argmax(free, axis=2).astype(jnp.int32)
         hit = (jnp.arange(p)[None, None] == slot[..., None])
         newly = jnp.take_along_axis(free, slot[..., None], axis=2)[..., 0]
-        pool, phys = self.pool, self.phys
-        if pool is not None:
-            pool, phys = block_pool.token_write(
-                pool, phys, slot[..., None], k_new, v_new,
-                event_mask(active, slot.shape)[..., None])
-            k, v = self.k, self.v       # zero-width; bytes go to the pool
-        else:
-            k = jnp.where(hit[..., None], k_new.astype(self.k.dtype), self.k)
-            v = jnp.where(hit[..., None], v_new.astype(self.v.dtype), self.v)
+        k, v, pool, phys = store_token(self, slot, k_new, v_new, active)
         return dataclasses.replace(
             self,
             k=k, v=v,
@@ -293,11 +278,9 @@ class QuestCache(LaneSliceable):
                 event_mask(active, (b, h, 1)))
             k, v = self.k, self.v       # zero-width; bytes go to the pool
         else:
-            def upd(buf, new, off):
-                return jax.lax.dynamic_update_slice_in_dim(buf, new, off, axis=1)
-
-            k = jax.vmap(upd)(self.k, k_new.astype(self.k.dtype), t)
-            v = jax.vmap(upd)(self.v, v_new.astype(self.v.dtype), t)
+            slot, act = _append_slots(t, 1, self.k.shape[:3], active)
+            k = write_rows(self.k, slot, k_new, act)
+            v = write_rows(self.v, slot, v_new, act)
         page = t // self.page_size                          # (B,)
         kf = k_new[..., 0, :].astype(jnp.float32)
         n_pages = self.kmin.shape[2]
@@ -426,41 +409,40 @@ class DMCCache(LaneSliceable):
         p_idx = jnp.arange(p)
         hit = p_idx[None, None] == tgt[..., None]
         pool, phys = self.pool, self.phys
-        if pool is not None:
-            # row-level twin of the dense formula below: gather the merge
-            # target's accumulator row, blend, write back through the page
-            # map (same op order, so bitwise-equal at slot ``tgt``)
+
+        def blend(k_old, v_old):
+            """The merge target's rows (B, H, Dh) after absorbing this
+            token: their weighted average with the new (k, v), or the new
+            (k, v) alone where the step appends."""
             z_tgt = jnp.take_along_axis(self.z, tgt[..., None], axis=2)[..., 0]
-            z_old_r = jnp.where(merge, z_tgt, 0.0)
-            z_new_r = z_old_r + omega
-            k_old = block_pool.gather_rows(pool.k, phys, tgt, self.block_p)
-            v_old = block_pool.gather_rows(pool.v, phys, tgt, self.block_p)
-            k_row = (jnp.where(merge[..., None], k_old, 0.0) * z_old_r[..., None]
-                     + kf * omega[..., None]) / z_new_r[..., None]
-            v_row = (jnp.where(merge[..., None], v_old, 0.0) * z_old_r[..., None]
-                     + vf * omega[..., None]) / z_new_r[..., None]
-            # tgt == P (arena full) is a silent drop in the dense path; mask
-            # it here too so the clamp in token_write can't hit a live page
-            wm = event_mask(active, (b, h)) & (tgt < p)
-            pool, phys = block_pool.token_write(
-                pool, phys, tgt[..., None], k_row[..., None, :],
-                v_row[..., None, :], wm[..., None])
+            z_old = jnp.where(merge, z_tgt, 0.0)
+            z_new = z_old + omega
+            k_row = (jnp.where(merge[..., None], k_old, 0.0) * z_old[..., None]
+                     + kf * omega[..., None]) / z_new[..., None]
+            v_row = (jnp.where(merge[..., None], v_old, 0.0) * z_old[..., None]
+                     + vf * omega[..., None]) / z_new[..., None]
+            return k_row[..., None, :], v_row[..., None, :]
+
+        # tgt == P (arena full) writes nothing
+        wm = (event_mask(active, (b, h)) & (tgt < p))[..., None]
+        if pool is not None:
+            k_row, v_row = blend(
+                block_pool.gather_rows(pool.k, phys, tgt, self.block_p),
+                block_pool.gather_rows(pool.v, phys, tgt, self.block_p))
+            pool, phys = block_pool.token_write(pool, phys, tgt[..., None],
+                                                k_row, v_row, wm)
             k, v = self.k, self.v       # zero-width; bytes go to the pool
         else:
-            z_old = jnp.where(merge[..., None], self.z, 0.0)
-            z_new = z_old + omega[..., None]
-            k_upd = (jnp.where(merge[..., None, None], self.k, 0.0) * z_old[..., None]
-                     + kf[..., None, :] * omega[..., None, None]) / z_new[..., None]
-            v_upd = (jnp.where(merge[..., None, None], self.v, 0.0) * z_old[..., None]
-                     + vf[..., None, :] * omega[..., None, None]) / z_new[..., None]
-            k = jnp.where(hit[..., None], k_upd, self.k)
-            v = jnp.where(hit[..., None], v_upd, self.v)
+            k_row, v_row = blend(read_rows(self.k, tgt),
+                                 read_rows(self.v, tgt))
+            k = write_rows(self.k, tgt[..., None], k_row, wm)
+            v = write_rows(self.v, tgt[..., None], v_row, wm)
         z = jnp.where(hit, jnp.where(merge[..., None], self.z, 0.0) + omega[..., None],
                       self.z)
         count = jnp.where(merge, self.count, self.count + 1)
         # a merged entry is "as recent as" its newest contribution: stamp the
         # touched slot with the current position so layer_map window layers
-        # can mask DMC entries (no active masking — lane_select rolls back)
+        # can mask DMC entries (small leaves: the lane freeze rolls them back)
         pos = jnp.where(hit, self.length[:, None, None], self.pos)
         return dataclasses.replace(self, k=k, v=v, z=z, count=count,
                                    length=self.length + 1, pos=pos,

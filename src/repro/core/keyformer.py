@@ -27,7 +27,7 @@ from repro.core import block_pool
 from repro.core.config import ArchConfig, KVPolicyConfig
 from repro.core.kv_cache import (INVALID_POS, BlockTable, HasBlockTable,
                                  LaneSliceable, _round_up, _tree_dataclass,
-                                 event_mask, init_paged)
+                                 event_mask, init_paged, store_token)
 from repro.core.policy import KVPolicy, _attend_spec, register_policy
 
 _SCORE_EPS = 1e-9
@@ -82,15 +82,7 @@ class KeyformerCache(LaneSliceable, HasBlockTable):
         slot = jnp.argmax(free, axis=2).astype(jnp.int32)         # first free
         hit = (jnp.arange(p)[None, None] == slot[..., None])
         newly = jnp.take_along_axis(free, slot[..., None], axis=2)[..., 0]
-        pool, phys = self.pool, self.phys
-        if pool is not None:
-            pool, phys = block_pool.token_write(
-                pool, phys, slot[..., None], k_new, v_new,
-                event_mask(active, slot.shape)[..., None])
-            k, v = self.k, self.v       # zero-width; bytes go to the pool
-        else:
-            k = jnp.where(hit[..., None], k_new.astype(self.k.dtype), self.k)
-            v = jnp.where(hit[..., None], v_new.astype(self.v.dtype), self.v)
+        k, v, pool, phys = store_token(self, slot, k_new, v_new, active)
         # Stash the layer salt for this step's Gumbel draw.  It must be
         # derived from something bit-identical between the kernel and
         # reference attention paths — activations (k_new, attention weights)

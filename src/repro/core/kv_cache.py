@@ -99,6 +99,83 @@ def _tree_dataclass(cls):
 
 
 # ---------------------------------------------------------------------------
+# Arena rows: the only way a decode step touches a fixed K/V arena
+# ---------------------------------------------------------------------------
+#
+# A step adds one token row per (lane, kv head) and the kernel reads the
+# arena where it lies; nothing on the step path reads or rewrites a whole
+# arena (docs/policies.md).  Inside the decode scan a cache's ``k``/``v``
+# are LayerArena views into the layer-stacked buffer the scan carries, so
+# the row write below lands in that buffer in place and the kernel reads
+# its layer from the same buffer by index.
+
+
+@_tree_dataclass
+class LayerArena:
+    """Layer ``layer`` of a layer-stacked K or V arena ``stack``
+    (L, B, H, P, Dh): what a cache's ``k``/``v`` hold inside the decode
+    scan.  ``shape``/``dtype`` are the one layer's, so shape-derived cache
+    code sees the (B, H, P, Dh) arena it always saw."""
+
+    stack: jnp.ndarray
+    layer: jnp.ndarray      # () int32
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        return self.stack.shape[1:]
+
+    @property
+    def dtype(self):
+        return self.stack.dtype
+
+
+def arena_view(arena) -> jnp.ndarray:
+    """The (B, H, P, Dh) arena as an array: a LayerArena's layer is sliced
+    out of its stack (a copy of the layer — the reference attention path,
+    never the kernel's)."""
+    if isinstance(arena, LayerArena):
+        return jax.lax.dynamic_index_in_dim(arena.stack, arena.layer, 0,
+                                            keepdims=False)
+    return arena
+
+
+def arena_stack(arena) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(stack (L, B, H, P, Dh), layer)`` holding ``arena``: what the
+    decode kernel reads in place (a plain arena is a stack of one)."""
+    if isinstance(arena, LayerArena):
+        return arena.stack, arena.layer
+    return arena[None], jnp.zeros((), jnp.int32)
+
+
+def write_rows(arena, slot: jnp.ndarray, rows: jnp.ndarray,
+               mask: jnp.ndarray):
+    """Write ``rows`` (B, H, T, Dh) at slots ``slot`` (B, H, T) of each
+    (lane, head) where ``mask`` (B, H, T) holds; masked-out and
+    out-of-range rows are not written.  Returns the arena in the form it
+    came in (an array, or a LayerArena whose stack took the rows)."""
+    b, h, _ = slot.shape
+    dst = jnp.where(mask, slot, arena.shape[2])        # out of range: dropped
+    bi = jnp.arange(b)[:, None, None]
+    hi = jnp.arange(h)[None, :, None]
+    rows = rows.astype(arena.dtype)
+    if isinstance(arena, LayerArena):
+        return dataclasses.replace(arena, stack=arena.stack.at[
+            arena.layer, bi, hi, dst].set(rows, mode="drop"))
+    return arena.at[bi, hi, dst].set(rows, mode="drop")
+
+
+def read_rows(arena, slot: jnp.ndarray) -> jnp.ndarray:
+    """One row per (lane, head): ``slot`` (B, H) -> (B, H, Dh)."""
+    b, h = slot.shape
+    bi = jnp.arange(b)[:, None]
+    hi = jnp.arange(h)[None, :]
+    slot = jnp.clip(slot, 0, arena.shape[2] - 1)
+    if isinstance(arena, LayerArena):
+        return arena.stack[arena.layer, bi, hi, slot]
+    return arena[bi, hi, slot]
+
+
+# ---------------------------------------------------------------------------
 # Block tables: compacted live-block indices for the flash-decode kernel
 # ---------------------------------------------------------------------------
 
@@ -283,13 +360,38 @@ def init_paged(batch: int, kv_heads: int, padded_slots: int, head_dim: int,
 
 def event_mask(active, shape) -> jnp.ndarray:
     """Broadcast the scheduler's per-lane ``active`` mask (B,) over event
-    shape (B, H[, T]); None = all lanes live.  Pool mutations MUST be gated
-    on this: the pool is shared state that ``lane_select`` cannot roll back,
-    so inactive lanes may not allocate, free, or write pages."""
+    shape (B, H[, T]); None = all lanes live.  K/V writes MUST be gated on
+    this: the pool is shared state that ``lane_select`` cannot roll back,
+    and the decode step's freeze leaves the fixed arenas out of its select,
+    so inactive lanes may not allocate, free, or write pages or rows."""
     if active is None:
         return jnp.ones(shape, bool)
     return jnp.broadcast_to(active.reshape((-1,) + (1,) * (len(shape) - 1)),
                             shape)
+
+
+def store_token(cache, slot, k_new, v_new, active):
+    """Store one token a (lane, head) at ``slot`` (B, H): a lane-masked row
+    write into the fixed arena, or a page write through a paged cache's
+    map.  Returns the cache's new ``(k, v, pool, phys)``."""
+    act = event_mask(active, slot.shape)[..., None]
+    if cache.pool is None:
+        return (write_rows(cache.k, slot[..., None], k_new, act),
+                write_rows(cache.v, slot[..., None], v_new, act), None, None)
+    pool, phys = block_pool.token_write(cache.pool, cache.phys,
+                                        slot[..., None], k_new, v_new, act)
+    return cache.k, cache.v, pool, phys
+
+
+def _append_slots(length, t_new: int, bhs, active):
+    """Slots (B, H, T) and write mask of T tokens appended at each lane's
+    ``length`` into an S-slot arena, the start clamped into the arena as a
+    ``dynamic_update_slice`` clamps it (append-only caches)."""
+    b, h, s = bhs
+    start = jnp.clip(length, 0, s - t_new)
+    slot = jnp.broadcast_to(start[:, None, None] + jnp.arange(t_new),
+                            (b, h, t_new))
+    return slot, event_mask(active, (b, h, t_new))
 
 
 def cache_block_p(cache) -> int:
@@ -388,12 +490,11 @@ class VanillaCache(LaneSliceable):
             return dataclasses.replace(self, pool=pool, phys=phys,
                                        length=self.length + t_new)
 
-        def upd(buf, new, off):
-            return jax.lax.dynamic_update_slice_in_dim(buf, new, off, axis=1)
-
-        k = jax.vmap(upd)(self.k, k_new.astype(self.k.dtype), self.length)
-        v = jax.vmap(upd)(self.v, v_new.astype(self.v.dtype), self.length)
-        return dataclasses.replace(self, k=k, v=v, length=self.length + t_new)
+        slot, act = _append_slots(self.length, t_new, self.k.shape[:3],
+                                  active)
+        return dataclasses.replace(
+            self, k=write_rows(self.k, slot, k_new, act),
+            v=write_rows(self.v, slot, v_new, act), length=self.length + t_new)
 
     def valid_mask(self) -> jnp.ndarray:
         # lazy (B, 1, S): broadcast happens inside the consumer's `where`
@@ -451,9 +552,13 @@ class MaskedDMSCache(LaneSliceable, HasBlockTable):
         s = self.k.shape[2]
         idx = jnp.arange(s)
         at_t = idx[None, None, :] == t[:, None, None]       # (B, 1, S)
+        b, h = self.retained.shape[:2]
+        ins = jnp.broadcast_to((t < s)[:, None], (b, h))
         if self.pool is None:
-            k = jnp.where(at_t[..., None], k_new.astype(self.k.dtype), self.k)
-            v = jnp.where(at_t[..., None], v_new.astype(self.v.dtype), self.v)
+            slot = jnp.broadcast_to(t[:, None, None], (b, h, 1))
+            act = (ins & event_mask(active, (b, h)))[..., None]
+            k = write_rows(self.k, slot, k_new, act)
+            v = write_rows(self.v, slot, v_new, act)
         else:
             k, v = self.k, self.v       # zero-width placeholders; bytes go
             #                             to the pool below
@@ -464,8 +569,6 @@ class MaskedDMSCache(LaneSliceable, HasBlockTable):
         evict_now = (idx[None, None, :] == j[:, None, None]) & alpha \
             & (j >= 0)[:, None, None]
         retained = retained & ~evict_now
-        b, h = self.retained.shape[:2]
-        ins = jnp.broadcast_to((t < s)[:, None], (b, h))
         blocks = self.blocks.insert(
             jnp.broadcast_to(t[:, None], (b, h)), ins)
         blocks, dead = blocks.evict_ex(
@@ -671,11 +774,7 @@ class SlotDMSCache(LaneSliceable, HasBlockTable):
         was_valid = jnp.take_along_axis(cache.valid, slot[..., None],
                                         axis=2)[..., 0]
         blocks = cache.blocks.insert(slot, ~was_valid)
-        if cache.pool is None:
-            k = jnp.where(hit[..., None], k_new.astype(cache.k.dtype), cache.k)
-            v = jnp.where(hit[..., None], v_new.astype(cache.v.dtype), cache.v)
-        else:
-            k, v = cache.k, cache.v     # zero-width; bytes go to the pool
+        k, v, pool, phys = store_token(cache, slot, k_new, v_new, active)
         pos = jnp.where(hit, t[:, None, None], cache.pos)
         valid = cache.valid | hit
         ring_idx = jnp.mod(t, cache.window)                               # (B,)
@@ -683,11 +782,6 @@ class SlotDMSCache(LaneSliceable, HasBlockTable):
         ring_hit = w_idx[None, None, :] == ring_idx[:, None, None]        # (B,1,w)
         pending_slot = jnp.where(ring_hit, slot[..., None], cache.pending_slot)
         pending_alpha = jnp.where(ring_hit, alpha_new[..., None], cache.pending_alpha)
-        pool, phys = cache.pool, cache.phys
-        if pool is not None:
-            act = event_mask(active, slot.shape)
-            pool, phys = block_pool.token_write(
-                pool, phys, slot[..., None], k_new, v_new, act[..., None])
         return dataclasses.replace(
             cache, k=k, v=v, pos=pos, valid=valid,
             pending_slot=pending_slot, pending_alpha=pending_alpha,

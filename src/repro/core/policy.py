@@ -780,22 +780,12 @@ class DMCPolicy(KVPolicy):
         if alpha is None:
             alpha = jnp.zeros((b, cfg.num_kv_heads), bool)
         cache = cache.step(k_new, v_new, alpha, active=aux.get("active"))
-        dtype = aux["dtype"]
-        tbl, n, bp = cache.block_spec()
-        if cache.pool is not None:
-            # the pool holds fp32 accumulators while the spec is model-dtype,
-            # so (unlike other paged caches) the kernel cannot stream pool
-            # pages directly: gather the dense view and cast, exactly the
-            # fixed-arena path — the cast output feeds the same kernel
-            kd, vd = block_pool.dense_kv(cache.pool, cache.phys)
-        else:
-            kd, vd = cache.k, cache.v
-        # merged entries carry their newest contribution's position, so
+        # the fp32 accumulators are read where they lie (the arena, or the
+        # pool's pages) at the query's precision, so nothing is cast here.
+        # Merged entries carry their newest contribution's position, so
         # layer_map window layers mask DMC slots like every other policy
         # (a merged entry is "as recent as" its last absorbed token)
-        return cache, AttendSpec(kd.astype(dtype), vd.astype(dtype),
-                                 cache.valid_mask(), cache.positions(),
-                                 block_tbl=tbl, block_n=n, block_p=bp)
+        return cache, _attend_spec(cache)
 
 
 # autoload policies that live in their own modules (each registers itself on

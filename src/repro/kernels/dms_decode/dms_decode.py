@@ -23,6 +23,11 @@ kernel's cost follows live blocks, not the table's width.  The G query
 heads of the group ride along as rows of the (G, Dh) q tile so GQA reuses
 each gathered KV tile across the whole group (the main arithmetic-intensity
 lever at decode time).
+
+The fixed arena arrives as the whole layer-stacked buffer the decode scan
+carries, with the layer's index scalar-prefetched: the copies read that
+layer's rows where they lie, so no per-layer slice of the arena is ever
+copied out for the call.
 """
 import functools
 from typing import NamedTuple, Optional
@@ -62,8 +67,8 @@ def blocks_per_pass(block_p: int) -> int:
     return max(1, 128 // block_p)
 
 
-def _decode_kernel(tbl_ref, n_ref, q_ref, k_hbm, v_hbm, valid_ref,
-                   o_ref, *rest, cfg: DecodeConfig):
+def _decode_kernel(tbl_ref, n_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                   valid_ref, o_ref, *rest, cfg: DecodeConfig):
     if cfg.weights_out:
         w_ref, mb_ref, mo_ref, lo_ref, kbuf, vbuf, sems = rest
     else:
@@ -72,8 +77,9 @@ def _decode_kernel(tbl_ref, n_ref, q_ref, k_hbm, v_hbm, valid_ref,
     h = pl.program_id(0)
     n = n_ref[h]
     n_pass = (n + c - 1) // c
-    # fixed mode: the head's own arena row; paged: the one shared page arena
-    src_row = 0 if cfg.shared_kv else h
+    # fixed mode: the head's own arena row in its layer's part of the
+    # layer-stacked arena; paged: the one shared page arena
+    src_row = 0 if cfg.shared_kv else layer_ref[0] * pl.num_programs(0) + h
 
     def pass_copies(p, slot, op):
         """``op`` ("start" or "wait") the K and V copies of every live entry
@@ -111,7 +117,9 @@ def _decode_kernel(tbl_ref, n_ref, q_ref, k_hbm, v_hbm, valid_ref,
             rows.append(r.astype(jnp.int32))
         live = (jnp.concatenate(rows, axis=-1) != 0) & (lane < live_n * bp)
 
-        k = kbuf[slot].astype(jnp.float32)               # (C·BP, Dh)
+        # K and V are read at no more than the query's precision (a wider
+        # arena, DMC's float32 accumulators, rounds as it is read)
+        k = kbuf[slot].astype(q_ref.dtype).astype(jnp.float32)  # (C·BP, Dh)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * (cfg.orig_dh ** -0.5)
@@ -124,7 +132,8 @@ def _decode_kernel(tbl_ref, n_ref, q_ref, k_hbm, v_hbm, valid_ref,
         l_new = corr * l_prev + jnp.sum(w, axis=-1, keepdims=True)
         # a slot never copied holds stale or uninitialised VMEM: its weight
         # is 0, but 0 * NaN is not, so its V rows are selected away
-        v = jnp.where(row < live_n * bp, vbuf[slot].astype(jnp.float32), 0.0)
+        v = jnp.where(row < live_n * bp,
+                      vbuf[slot].astype(q_ref.dtype).astype(jnp.float32), 0.0)
         acc_new = acc_prev * corr + jax.lax.dot_general(
             w, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -149,9 +158,10 @@ def _decode_kernel(tbl_ref, n_ref, q_ref, k_hbm, v_hbm, valid_ref,
 
 
 def smem_table_bytes(bh: int, nb_tbl: int) -> int:
-    """SMEM bytes the two scalar-prefetched operands take on the TPU: the
-    (BHkv, NB_tbl) int32 table and the (BHkv,) live counts, with rows padded
-    to 8 and the table's columns to 128 (what the TPU compiler allocates)."""
+    """SMEM bytes the two per-head scalar-prefetched operands take on the
+    TPU: the (BHkv, NB_tbl) int32 table and the (BHkv,) live counts, with
+    rows padded to 8 and the table's columns to 128 (what the TPU compiler
+    allocates).  The one-int32 layer index rides in the reserve."""
     rows = -(-bh // 8) * 8
     return rows * (-(-nb_tbl // 128) * 128) * 4 + rows * 4
 
@@ -168,7 +178,8 @@ def check_smem(bh: int, nb_tbl: int) -> None:
             f"or fewer lanes / a shorter arena.")
 
 
-def decode_fwd(q, k, v, valid, block_tbl, block_n, cfg: DecodeConfig):
+def decode_fwd(q, k, v, valid, block_tbl, block_n, cfg: DecodeConfig,
+               layer=0):
     """q: (BHkv, G, Dh); block_n: (BHkv,) int32 live counts.
     Returns (BHkv, G, Dh) — or, with ``cfg.weights_out``, the tuple
     ``(out, w_blk, m_blk, m_out, l_out)`` where ``w_blk`` is
@@ -181,7 +192,10 @@ def decode_fwd(q, k, v, valid, block_tbl, block_n, cfg: DecodeConfig):
     weight bytes ∝ table width (never arena capacity).  Rows at or past a
     head's ``n`` hold garbage; the wrapper masks them.
 
-    Fixed-arena mode: k/v (BHkv, P, Dh) with P a block_p multiple; valid
+    Fixed-arena mode: k/v (L·BHkv, P, Dh) with P a block_p multiple — the
+    layer-stacked arena the decode scan carries, of which layer ``layer``
+    (an int32 scalar, prefetched) is read in place: head ``h`` reads HBM
+    row ``layer·BHkv + h`` (L = 1, ``layer`` 0 for a single arena); valid
     (BHkv, P) in its stored dtype (bool/int — only ``!= 0`` is used);
     block_tbl (BHkv, NB_tbl) int32 compacted live block ids into the head's
     own arena.
@@ -207,14 +221,14 @@ def decode_fwd(q, k, v, valid, block_tbl, block_n, cfg: DecodeConfig):
     # entry (paged) — and the per-row stats as (..., G, 1).  Metadata-only
     # reshapes, here and on return.
     valid = valid.reshape(bh, -1, bp)
-    head = lambda h, tbl, n: (h, 0, 0)
+    head = lambda h, tbl, n, layer: (h, 0, 0)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
 
     out_specs = pl.BlockSpec((1, g, dh), head)
     out_shape = jax.ShapeDtypeStruct((bh, g, dh), q.dtype)
     if cfg.weights_out:
         # one row of weights per pass, written whole with the head's output
-        passes = lambda h, tbl, n: (h, 0, 0, 0)
+        passes = lambda h, tbl, n, layer: (h, 0, 0, 0)
         out_specs = [
             out_specs,
             pl.BlockSpec((1, n_pass, g, c * bp), passes),
@@ -231,7 +245,7 @@ def decode_fwd(q, k, v, valid, block_tbl, block_n, cfg: DecodeConfig):
         ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(bh,),
         in_specs=[
             pl.BlockSpec((1, g, dh), head),
@@ -252,7 +266,8 @@ def decode_fwd(q, k, v, valid, block_tbl, block_n, cfg: DecodeConfig):
         out_shape=out_shape,
         interpret=cfg.interpret,
         name="dms_decode",
-    )(block_tbl, block_n, q, k, v, valid)
+    )(block_tbl, block_n, jnp.asarray(layer, jnp.int32).reshape(1), q, k, v,
+      valid)
     if not cfg.weights_out:
         return res
     out, w_pass, m_pass, m_out, l_out = res

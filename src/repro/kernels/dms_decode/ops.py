@@ -8,7 +8,10 @@ Two call modes (docs/kernels.md):
   kernel-native per-(lane, kv-head) layout, so this wrapper is **copy-free**
   — the (B, Hkv, …) → (B·Hkv, …) merges are metadata-only reshapes, there is
   no ``jnp.pad``, no ``valid`` dtype cast, and no full-arena liveness
-  reduction on the step path.  HBM traffic ∝ live blocks.
+  reduction on the step path.  HBM traffic ∝ live blocks.  Inside the
+  decode scan ``k``/``v`` are the whole layer-stacked arena
+  (L, B, Hkv, P, Dh) and ``layer`` the layer's index: the kernel reads that
+  layer's rows in place, so no slice of the stack is copied for the call.
 
   With ``pool_k``/``pool_v``/``phys`` also given (paged caches — see
   ``repro.core.block_pool``) the kernel streams the shared page arena
@@ -68,7 +71,7 @@ def modeled_hbm_bytes(block_n, block_p: int, head_dim: int,
 
 def dms_decode_attention(
     q: jnp.ndarray,       # (B, 1, Hq, Dh)
-    k: jnp.ndarray,       # (B, Hkv, P, Dh)
+    k: jnp.ndarray,       # (B, Hkv, P, Dh), or (L, B, Hkv, P, Dh) with layer
     v: jnp.ndarray,
     valid: jnp.ndarray,   # (B, Hkv, P) bool (stored dtype — never cast here)
     *,
@@ -81,9 +84,12 @@ def dms_decode_attention(
     pool_v: Optional[jnp.ndarray] = None,
     phys: Optional[jnp.ndarray] = None,        # (B, Hkv, NB) page map, -1 free
     need_weights: bool = False,
+    layer=None,           # int32 scalar: k/v are a layer-stacked arena
 ) -> jnp.ndarray:
+    if layer is None:
+        k, v, layer = k[None], v[None], 0
     b, _, hq, dh = q.shape
-    hkv, p = k.shape[1], k.shape[2]
+    n_layers, _, hkv, p = k.shape[:4]
     g = hq // hkv
     if interpret is None:
         interpret = _default_interpret()
@@ -124,14 +130,22 @@ def dms_decode_attention(
                 valid.reshape(b, hkv, p // bp, bp),
                 jnp.clip(block_tbl, 0, p // bp - 1)[..., None], axis=2,
             ).reshape(b * hkv, -1)
+            layer = 0
         else:
-            kf, vf = k.reshape(b * hkv, p, dh), v.reshape(b * hkv, p, dh)
+            # the stack as the kernel's rows: layer l's head h is row
+            # l·B·Hkv + h (a metadata-only reshape: the kernel reads the
+            # layer in place)
+            kf = k.reshape(n_layers * b * hkv, p, dh)
+            vf = v.reshape(n_layers * b * hkv, p, dh)
             valf = valid.reshape(b * hkv, p)
     else:
         # legacy/dense path: derive a written-prefix-of-blocks table from
         # `valid` (O(P) reduction + pad — NOT the policy step path)
         bp = min(block_p or DEFAULT_BLOCK_P, _round_up(p, 8))
         pp = _round_up(p, bp)
+        k, v = (jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+                for a in (k, v))
+        layer = 0
         kf = jnp.pad(k.reshape(b * hkv, p, dh), ((0, 0), (0, pp - p), (0, 0)))
         vf = jnp.pad(v.reshape(b * hkv, p, dh), ((0, 0), (0, pp - p), (0, 0)))
         valf = jnp.pad(valid.reshape(b * hkv, p), ((0, 0), (0, pp - p)))
@@ -147,11 +161,11 @@ def dms_decode_attention(
                        interpret=bool(interpret), shared_kv=shared_kv,
                        weights_out=need_weights)
     if not need_weights:
-        out = decode_fwd(qf, kf, vf, valf, tblf, nf, cfg)
+        out = decode_fwd(qf, kf, vf, valf, tblf, nf, cfg, layer)
         return out.reshape(b, hkv, g, dh).reshape(b, 1, hq, dh)
 
     out, w_blk, m_blk, m_out, l_out = decode_fwd(qf, kf, vf, valf, tblf, nf,
-                                                 cfg)
+                                                 cfg, layer)
     # Renormalize per table row: each block's weights were emitted as
     # exp(s - m_blk) with m_blk the running max at that block; the true
     # softmax weight is exp(s - m_out) / l_out.  For live rows

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.core import dms as dms_lib
 from repro.core import policy as policy_lib
+from repro.core.kv_cache import arena_stack, arena_view
 from repro.core.config import ArchConfig, AttentionConfig
 from repro.models.layers import apply_rope, dense_init, softcap
 
@@ -467,8 +468,11 @@ def decode_attention(
 def _masked_decode(q, spec, window, cfg, use_kernel,
                    pos_t=None, need_weights=False):
     """q: (B,1,Hq,Dh); ``spec``: an :class:`repro.core.policy.AttendSpec`
-    (k/v: (B,Hkv,P,Dh), visible: (B,Hkv,P) bool, optional block table);
-    pos_t: per-lane (B,) current positions (or scalar).
+    (k/v: (B,Hkv,P,Dh) arenas — inside the decode scan, LayerArena views of
+    the layer-stacked arena — visible: (B,Hkv,P) bool, optional block
+    table); pos_t: per-lane (B,) current positions (or scalar).  K and V
+    are read at no more than the query's precision: a wider arena (DMC's
+    float32 accumulators) rounds to the query's dtype as it is read.
 
     Local-window layers additionally hide slots with position <= t - window
     (a *subset* restriction of ``spec.visible``, so the spec's live-block
@@ -486,15 +490,18 @@ def _masked_decode(q, spec, window, cfg, use_kernel,
         vis = vis & (pos > (ptl[:, None, None] - window))
     if use_kernel:
         from repro.kernels.dms_decode import ops as dkops
+        # the kernel reads the layer from its stack in place
+        (k, layer), (v, _) = arena_stack(k), arena_stack(v)
         res = dkops.dms_decode_attention(
             q, k, v, vis, block_tbl=spec.block_tbl, block_n=spec.block_n,
             block_p=spec.block_p or None, logit_cap=cfg.logit_softcap,
             pool_k=spec.pool_k, pool_v=spec.pool_v, phys=spec.phys,
-            need_weights=need_weights)
+            need_weights=need_weights, layer=layer)
         if need_weights:
             out, weights = res
             return out, weights, "kernel"
         return res, None, "kernel"
+    k, v = (_at_most(arena_view(a), q.dtype) for a in (k, v))
     # MXU-style mixed precision: bf16 operands, fp32 accumulation — the cache
     # is never converted/materialised in fp32 (that would double decode traffic)
     qg = q[:, 0].reshape(b, hkv, g, dh).astype(k.dtype)
@@ -508,6 +515,13 @@ def _masked_decode(q, spec, window, cfg, use_kernel,
                      preferred_element_type=jnp.float32)
     out = out.reshape(b, 1, hq, dh).astype(q.dtype)
     return out, (jnp.sum(w, axis=2) if need_weights else None), "ref"
+
+
+def _at_most(a: jnp.ndarray, dtype) -> jnp.ndarray:
+    """``a`` rounded to ``dtype`` where ``dtype`` is the narrower."""
+    if jnp.dtype(a.dtype).itemsize > jnp.dtype(dtype).itemsize:
+        return a.astype(dtype)
+    return a
 
 
 def _cache_length(cache) -> jnp.ndarray:

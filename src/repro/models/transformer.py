@@ -22,6 +22,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import kv_cache
 from repro.core import policy as policy_lib
 from repro.core.config import ArchConfig, KVPolicyConfig
 from repro.models import attention as attn_lib
@@ -552,6 +553,12 @@ def decode_step(
     but the state write is discarded) and it contributes zero to the
     ``reads_tokens`` budget axis.  This is what makes continuous batching
     honest: finished or idle lanes neither mutate state nor inflate meters.
+
+    The fixed K/V arenas ride the layer scan's carry as the layer-stacked
+    buffers they are stored as (:func:`_split_arenas`): each layer writes
+    its token rows into its part in place, lane-masked, and the kernel
+    reads the layer there.  So no step copies, stacks or selects a whole
+    arena; the lane freeze below selects every other leaf.
     """
     x = (embed_override if embed_override is not None
          else embed_tokens(params, token, arch))
@@ -567,17 +574,23 @@ def decode_step(
                              use_kernel=use_kernel, layer=layer)
 
     def body(carry, xs):
-        x_t, live, reads, pairs = carry
-        blk, cache, li = xs if experts else xs + (None,)
-        new_caches = {}
+        x_t, live, reads, pairs, arenas = carry
+        blk, cache, li = xs
+        new_caches, arenas = {}, dict(arenas)
         for pi, kind in enumerate(arch.layer_pattern):
             p = blk[str(pi)]
             if kind in ("attn", "attn_local"):
                 h = norm_apply(p["attn_norm"], x_t, arch.norm, arch.norm_eps)
+                c = cache[str(pi)]
+                if str(pi) in arenas:     # read and write layer li in place
+                    c = _with_kv(c, *(kv_cache.LayerArena(a, li)
+                                      for a in arenas[str(pi)]))
                 a_out, new_c, aux = attn_lib.decode_attention(
-                    p["attn"], h, cache[str(pi)], _layer_attn(arch, kind), arch,
+                    p["attn"], h, c, _layer_attn(arch, kind), arch,
                     layer_window=_layer_window(arch, kind), pos_t=pos_t,
                     use_kernel=use_kernel, active=active)
+                if str(pi) in arenas:
+                    new_c, arenas[str(pi)] = _take_arenas(new_c)
                 impls.add(aux["attn_impl"])
                 if arch.post_norm:
                     a_out = norm_apply(p["attn_post_norm"], a_out, arch.norm, arch.norm_eps)
@@ -623,32 +636,34 @@ def decode_step(
                     pairs = pairs + m_aux.get("moe_pairs", 0)
                     x_t = x_t + m_out
             new_caches[str(pi)] = new_c
-        return (x_t, live, reads, pairs), new_caches
+        return (x_t, live, reads, pairs, arenas), new_caches
 
     b = x.shape[0]
     zero = jnp.zeros((b,), jnp.float32)
-    carry0 = (x, zero, zero, jnp.zeros((b,), jnp.int32))
+    rest, arenas = _split_arenas(state)
+    carry0 = (x, zero, zero, jnp.zeros((b,), jnp.int32), arenas)
     nsb = arch.num_superblocks
-    # each superblock's index, for the expert layers to read their weights
-    idx = (jnp.arange(nsb),) if experts else ()
+    # each superblock's index: the layer the arenas and the expert layers'
+    # weights are read at
     if scan_layers:
-        (x, live, reads, pairs), new_state = jax.lax.scan(
-            body, carry0, (blocks, state) + idx)
+        (x, live, reads, pairs, arenas), new_rest = jax.lax.scan(
+            body, carry0, (blocks, rest, jnp.arange(nsb)))
     else:
         carry = carry0
         outs = []
         for s in range(nsb):
             blk_s = jax.tree_util.tree_map(lambda a: a[s], blocks)
-            st_s = jax.tree_util.tree_map(lambda a: a[s], state)
-            carry, y = body(carry, (blk_s, st_s, s) if experts
-                            else (blk_s, st_s))
+            st_s = jax.tree_util.tree_map(lambda a: a[s], rest)
+            carry, y = body(carry, (blk_s, st_s, jnp.int32(s)))
             outs.append(y)
-        (x, live, reads, pairs) = carry
-        new_state = jax.tree_util.tree_map(lambda *zs: jnp.stack(zs), *outs)
+        (x, live, reads, pairs, arenas) = carry
+        new_rest = jax.tree_util.tree_map(lambda *zs: jnp.stack(zs), *outs)
     if active is not None:
+        # the arenas took lane-masked row writes: only the rest is frozen
         with jax.named_scope("lane_select"):
-            new_state = lane_select(active, new_state, state)
+            new_rest = lane_select(active, new_rest, rest)
         reads = reads * active.astype(reads.dtype)
+    new_state = _merge_arenas(new_rest, arenas)
     with jax.named_scope("sample"):
         logits = lm_logits(params, x, arch)[:, 0]
     # static int (i32 under jit, lint-clean): 1 iff every attention layer
@@ -660,6 +675,38 @@ def decode_step(
     return logits, new_state, {"live_tokens": live, "reads_tokens": reads,
                                "attn_impl_kernel": kernel_only,
                                "moe_pairs": pairs}
+
+
+def _split_arenas(state: Dict[str, Any]) -> Tuple[Dict[str, Any], dict]:
+    """(the decode state with each fixed K/V arena taken out, those arenas
+    as ``{pattern position: (k, v)}``, still stacked over superblocks).
+
+    A cache's arenas are taken when they have a row width, which is what a
+    fixed arena has and a paged cache's zero-width placeholders lack (the
+    pool keeps its own route through the scan)."""
+    rest, arenas = dict(state), {}
+    for pi, node in state.items():
+        if _is_policy_cache(node) and node.cache.k.shape[-1]:
+            rest[pi], arenas[pi] = _take_arenas(node)
+    return rest, arenas
+
+
+def _merge_arenas(rest: Dict[str, Any], arenas: dict) -> Dict[str, Any]:
+    """The inverse of :func:`_split_arenas`."""
+    return {**rest, **{pi: _with_kv(rest[pi], *kv)
+                       for pi, kv in arenas.items()}}
+
+
+def _take_arenas(node):
+    """(``node`` with its cache's ``k``/``v`` set to None, the two arrays —
+    a LayerArena gives back its stack)."""
+    kv = tuple(getattr(a, "stack", a) for a in (node.cache.k, node.cache.v))
+    return _with_kv(node, None, None), kv
+
+
+def _with_kv(node, k, v):
+    return dataclasses.replace(node, cache=dataclasses.replace(
+        node.cache, k=k, v=v))
 
 
 def _split_experts(blocks: dict, arch: ArchConfig) -> Tuple[dict, dict]:
@@ -693,7 +740,9 @@ def lane_select(mask: jnp.ndarray, on_true: Any, on_false: Any) -> Any:
     with no lane axis: the updated pool is kept unconditionally — its
     mutation helpers already took the lane event mask, so inactive lanes
     produced no pool events to roll back (their per-lane ``phys`` page map
-    rolls back here like any other leaf).
+    rolls back here like any other leaf).  :func:`decode_step` freezes the
+    state with its fixed K/V arenas taken out, for the same reason: their
+    row writes took the mask.
     """
 
     def sel(a, b):

@@ -131,6 +131,62 @@ class TestArenaGather:
                              table_mode=True, passes=["arena-gather"])
 
 
+class TestArenaSelect:
+    @staticmethod
+    def _where_write(arena, kn, slot):
+        # a token write as a select over the whole arena
+        hit = jnp.arange(ARENA[2])[None, None, :, None] == slot
+        return jnp.where(hit, kn, arena)
+
+    @staticmethod
+    def _select(fn, *args, table_mode=True, **ctx):
+        ctx = LintContext(arena_elems=ctx.pop("arena_elems", ELEMS),
+                          table_mode=table_mode, **ctx)
+        return run_passes(fn, ctx, *args, passes=["arena-select"])
+
+    def test_red_where_token_write(self):
+        got = self._select(self._where_write, jnp.zeros(ARENA),
+                           jnp.zeros((2, 2, 1, 4)), jnp.int32(3))
+        assert _rules(got) == ["arena-select"]
+
+    def test_red_layer_written_into_stack(self):
+        # a layer's arena written back whole: a scan's stacked output
+        def step(stack, arena, layer):
+            return jax.lax.dynamic_update_index_in_dim(stack, arena, layer, 0)
+
+        got = self._select(step, jnp.zeros((3,) + ARENA), jnp.zeros(ARENA),
+                           jnp.int32(1), arena_elems=3 * ELEMS,
+                           layer_elems=ELEMS)
+        assert _rules(got) == ["arena-select"]
+
+    def test_red_lane_freeze_over_fixed_arenas(self, fixed_state):
+        # the lane freeze selecting the K/V arenas against their old values
+        _, state = fixed_state
+        elems, layer = _lint_elems(state)
+        got = self._select(tfm.lane_select, jnp.ones((2,), bool), state,
+                           state, arena_elems=elems, layer_elems=layer)
+        assert _rules(got) == ["arena-select"]
+
+    def test_green_in_place_row_write(self):
+        # one row per (lane, head) scattered into the layer of the stack
+        from repro.core.kv_cache import LayerArena, write_rows
+
+        def step(stack, kn, slot, layer):
+            return write_rows(LayerArena(stack, layer), slot, kn,
+                              jnp.ones(slot.shape, bool)).stack
+
+        got = self._select(step, jnp.zeros((3,) + ARENA),
+                           jnp.zeros((2, 2, 1, 4)),
+                           jnp.full((2, 2, 1), 3, jnp.int32), jnp.int32(1),
+                           arena_elems=3 * ELEMS, layer_elems=ELEMS)
+        assert not got
+
+    def test_green_ref_mode_is_silent(self):
+        assert not self._select(self._where_write, jnp.zeros(ARENA),
+                                jnp.zeros((2, 2, 1, 4)), jnp.int32(3),
+                                table_mode=False)
+
+
 class TestRefFallback:
     @staticmethod
     def _ref_attention(q, arena):
@@ -216,18 +272,35 @@ def paged_state(tiny_arch):
     return cfg, tfm.init_decode_state(tiny_arch, 2, 32, cfg)
 
 
-def test_decode_step_lints_clean(tiny_arch, tiny_params, paged_state):
-    cfg, state = paged_state
-    elems = min(int(np.prod((pc.cache.pool.k if pc.cache.pool is not None
-                             else pc.cache.k).shape))
-                for pc in analysis_iter(state))
+@pytest.fixture
+def fixed_state(tiny_arch):
+    cfg = KVPolicyConfig(kind="dms", cr=2.0, window=4, block_p=8)
+    return cfg, tfm.init_decode_state(tiny_arch, 2, 32, cfg)
+
+
+def _lint_elems(state):
+    """(smallest whole stacked arena or pool, smallest one layer's) in
+    elements, over the state's caches."""
+    arrs = [pc.cache.pool.k if pc.cache.pool is not None else pc.cache.k
+            for pc in analysis_iter(state)]
+    return (min(int(np.prod(a.shape)) for a in arrs),
+            min(int(np.prod(a.shape[1:])) for a in arrs))
+
+
+def test_decode_step_lints_clean(tiny_arch, tiny_params, paged_state,
+                                 fixed_state):
     tok = jnp.zeros((2, 1), jnp.int32)
     pos = jnp.zeros((2,), jnp.int32)
-    for use_kernel in (False, True):
+    act = jnp.array([True, False])
+    for (_, state), use_kernel in [(s, k) for s in (paged_state, fixed_state)
+                                   for k in (False, True)]:
+        elems, layer = _lint_elems(state)
         jaxpr = dce(trace_jaxpr(
             lambda s: tfm.decode_step(tiny_params, tok, s, tiny_arch, pos,
-                                      use_kernel=use_kernel), state))
-        ctx = LintContext(arena_elems=elems, table_mode=use_kernel)
+                                      use_kernel=use_kernel, active=act),
+            state))
+        ctx = LintContext(arena_elems=elems, table_mode=use_kernel,
+                          layer_elems=layer)
         assert not gating(run_passes(jaxpr, ctx)), use_kernel
 
 

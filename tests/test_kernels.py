@@ -274,6 +274,33 @@ def test_decode_kernel_never_reads_unlisted_blocks(mode):
                                    rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("weights", [False, True], ids=["plain", "weights"])
+def test_decode_kernel_reads_layer_of_stacked_arena(weights):
+    """Given the layer-stacked arena (L, B, Hkv, P, Dh) and a traced layer
+    index, the kernel reads that layer in place (its rows sit at
+    ``layer·B·Hkv + h``): output and weights equal the kernel on the
+    layer's own slice, bit for bit, for every layer."""
+    from repro.core.kv_cache import BlockTable
+    n_layers, b, hq, hkv, p, dh, bp = 3, 2, 4, 2, 64, 16, 16
+    ks = jax.random.split(jax.random.PRNGKey(8), 4)
+    q = jax.random.normal(ks[0], (b, 1, hq, dh))
+    k = jax.random.normal(ks[1], (n_layers, b, hkv, p, dh))
+    v = jax.random.normal(ks[2], (n_layers, b, hkv, p, dh))
+    valid = jax.random.bernoulli(ks[3], 0.4, (b, hkv, p)).at[:, :, 0].set(True)
+    bt = BlockTable.from_valid(valid, bp)
+    kw = dict(block_tbl=bt.tbl, block_n=bt.n, block_p=bp, need_weights=weights)
+    stacked = jax.jit(lambda layer: dops.dms_decode_attention(
+        q, k, v, valid, layer=layer, **kw))
+    sliced = jax.jit(lambda k_l, v_l: dops.dms_decode_attention(
+        q, k_l, v_l, valid, **kw))
+    for layer in range(n_layers):
+        got = stacked(jnp.int32(layer))
+        want = sliced(k[layer], v[layer])
+        for g, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_decode_kernel_partial_table_page_sparse():
     """A table listing only SOME live blocks (Quest top-k pages): the kernel
     must attend exactly to the listed blocks' visible slots."""

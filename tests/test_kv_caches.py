@@ -1,11 +1,18 @@
-"""Cache semantics: slot-compacted DMS == masked reference; baselines."""
+"""Cache semantics: slot-compacted DMS == masked reference; baselines; the
+decode step's arena contract (lane-masked row writes into carried arenas)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core import baselines
+from repro.core import baselines, block_pool, kv_cache
+from repro.core import policy as policy_lib
+from repro.core.config import KVPolicyConfig
 from repro.core.kv_cache import MaskedDMSCache, SlotDMSCache, VanillaCache
+from repro.models import transformer as tfm
 
 
 def _stream(seed, t, b=1, h=2, dh=4, p_evict=0.5):
@@ -152,3 +159,114 @@ def test_slot_cache_under_jit_and_scan(seed):
     for i in range(t):
         ref = ref.step(k[i], v[i], a[i])
     assert (final.retained_tokens() == ref.retained_tokens()).all()
+
+
+# ---------------------------------------------------------------------------
+# The decode step's arena contract: every fixed-arena K/V write is a
+# lane-masked row write into the layer-stacked arena the scan carries
+# ---------------------------------------------------------------------------
+
+LANES, MAX_LEN = 3, 32
+
+
+def _policy_state(arch, policy, paged):
+    cfg = KVPolicyConfig(kind=policy, cr=2.0, window=4, block_p=8,
+                         paged=paged, quest_page_size=8, quest_top_pages=2)
+    return tfm.init_decode_state(arch, LANES, MAX_LEN, cfg)
+
+
+def _stepper(params, arch, use_kernel=False, scan_layers=True):
+    return jax.jit(lambda s, tok, pos, act: tfm.decode_step(
+        params, tok, s, arch, pos, use_kernel=use_kernel, active=act,
+        scan_layers=scan_layers))
+
+
+def _lane_leaves(state):
+    """Every per-lane leaf of the decode state (lane axis 1), with a paged
+    cache's K/V as the dense view each lane's page map reaches."""
+    out = []
+    for pc in policy_lib.iter_policy_caches(state):
+        c = pc.cache
+        out += jax.tree_util.tree_leaves(dataclasses.replace(c, pool=None))
+        if c.pool is not None:
+            out += list(jax.vmap(block_pool.dense_kv)(c.pool, c.phys))
+    return out
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["fixed", "paged"])
+@pytest.mark.parametrize("policy", policy_lib.available_policies())
+def test_decode_step_leaves_inactive_lanes_unchanged(tiny_arch, tiny_params,
+                                                     policy, paged):
+    """After a few steps with every lane live, one step under a random
+    lane mask leaves every leaf of the inactive lanes bit for bit as it
+    was: the K/V rows they would have written are never written, and no
+    whole-arena select is left to roll them back."""
+    rng = np.random.default_rng(sum(map(ord, policy)) + paged)
+    step = _stepper(tiny_params, tiny_arch)
+    state = _policy_state(tiny_arch, policy, paged)
+    pos = np.zeros(LANES, np.int32)
+    tok = lambda: jnp.asarray(rng.integers(0, tiny_arch.vocab_size,
+                                           (LANES, 1)), jnp.int32)
+    for _ in range(5):
+        _, state, _ = step(state, tok(), jnp.asarray(pos),
+                           jnp.ones(LANES, bool))
+        pos += 1
+    active = rng.permutation([True, False] + [bool(rng.integers(2))]
+                             * (LANES - 2))
+    _, new, _ = step(state, tok(), jnp.asarray(pos), jnp.asarray(active))
+    for old, cur in zip(_lane_leaves(state), _lane_leaves(new)):
+        np.testing.assert_array_equal(np.asarray(cur)[:, ~active],
+                                      np.asarray(old)[:, ~active])
+    lengths = [np.asarray(pc.length)[0] for pc in
+               policy_lib.iter_policy_caches(new)]
+    assert all((n == pos + active).all() for n in lengths)
+
+
+def _where_rows(arena, slot, rows, mask):
+    """The token write of the per-layer route: a select over the whole
+    arena, every lane written (the lane freeze rolls inactive lanes back)."""
+    hit = jnp.arange(arena.shape[2]) == slot[..., :1]          # (B, H, P)
+    return jnp.where(hit[..., None], rows[:, :, :1].astype(arena.dtype),
+                     arena)
+
+
+@pytest.mark.parametrize("policy,use_kernel,scan_layers", [
+    (p, False, True) for p in policy_lib.available_policies()] + [
+    ("dms", True, True), ("tova", True, True), ("dms", False, False),
+    ("vanilla", False, False)])
+def test_carried_arenas_match_per_layer_step(tiny_arch, tiny_params, policy,
+                                             use_kernel, scan_layers,
+                                             monkeypatch):
+    """Over several steps under random lane masks, the decode step that
+    carries the layer-stacked arenas through the scan and writes rows in
+    place gives the active lanes bit-for-bit the logits, and the whole
+    state bit-for-bit the contents, of the per-layer route: each layer's
+    arena sliced into the scan, written by a whole-arena select, stacked
+    back, and every leaf frozen by the lane select.  ``scan_layers=False``
+    (layers unrolled) takes the same path."""
+    rng = np.random.default_rng(7)
+    steps = 6
+    toks = rng.integers(0, tiny_arch.vocab_size, (steps, LANES, 1))
+    masks = rng.random((steps, LANES)) < 0.7
+    masks[0], masks[-1, 1] = True, False   # the last step leaves a lane out
+    state0 = _policy_state(tiny_arch, policy, False)
+
+    def run():
+        step = _stepper(tiny_params, tiny_arch, use_kernel, scan_layers)
+        state, pos, logits = state0, np.zeros(LANES, np.int32), []
+        for tok, act in zip(toks, masks):
+            out, state, _ = step(state, jnp.asarray(tok, jnp.int32),
+                                 jnp.asarray(pos), jnp.asarray(act))
+            logits.append(np.asarray(out)[act])
+            pos += act
+        return logits, jax.tree_util.tree_leaves(state)
+
+    carried = run()
+    monkeypatch.setattr(tfm, "_split_arenas", lambda s: (s, {}))
+    for mod in (kv_cache, baselines):
+        monkeypatch.setattr(mod, "write_rows", _where_rows)
+    per_layer = run()
+    for got, want in zip(carried[0] + carried[1],
+                         per_layer[0] + per_layer[1]):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
